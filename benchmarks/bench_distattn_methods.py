@@ -66,8 +66,9 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.baselines import distattn_decode, ship_kv_decode, \
     tp_head_attention_decode
+from repro.launch.mesh import make_mesh
 
-mesh = jax.make_mesh((4,), ("x",))
+mesh = make_mesh((4,), ("x",))
 B, H, K, D, S = 4, 8, 8, 64, 8192
 key = jax.random.PRNGKey(0)
 q = jax.random.normal(key, (B, H, D), jnp.float32)
@@ -89,7 +90,7 @@ tp = jax.jit(jax.shard_map(
                          P(None, None, "x"), P()),
     out_specs=P(None, "x"), check_vma=False))
 
-with mesh:
+with jax.set_mesh(mesh):
     o1 = dist(q, k, v, mask); o2 = ship(q, k, v, mask)
     o3 = tp(q, k, v, mask)
 np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-4)
@@ -104,7 +105,7 @@ def timeit(f, *a):
     jax.block_until_ready(r)
     return (time.perf_counter() - t0) / 20 * 1e6
 
-with mesh:
+with jax.set_mesh(mesh):
     print(f"WALL,dist={timeit(dist,q,k,v,mask):.0f},"
           f"ship={timeit(ship,q,k,v,mask):.0f},"
           f"tp={timeit(tp,q,k,v,mask):.0f}")
@@ -116,15 +117,18 @@ def wall_clock():
         os.path.abspath(__file__))), "src")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # The child measures a CPU virtual-device mesh; it must never reach
+    # for an accelerator the parent process may already hold.
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", _WALL_SCRIPT, src],
                        capture_output=True, text=True, timeout=900,
                        env=env)
-    for line in r.stdout.splitlines():
-        if line.startswith("WALL"):
-            print("fig11_wallclock_us_cpu4dev," + line[5:])
-            return line
-    print("fig11_wallclock_us_cpu4dev,FAILED", r.stderr[-400:])
-    return None
+    if r.returncode != 0:
+        raise RuntimeError(f"wall-clock child failed (rc {r.returncode}):"
+                           f"\n{r.stderr[-2000:]}")
+    line = next(l for l in r.stdout.splitlines() if l.startswith("WALL"))
+    print("fig11_wallclock_us_cpu4dev," + line[5:])
+    return line
 
 
 def main():

@@ -37,6 +37,7 @@ def worker():
     import numpy as np
 
     from repro.configs import get_smoke_config
+    from repro.launch.mesh import make_mesh
     from repro.models.model import init_params
     from repro.serving import (Cluster, Request, SamplingParams,
                                ServingConfig)
@@ -48,7 +49,7 @@ def worker():
     rng = np.random.default_rng(0)
     out = []
     for R in RANKS:
-        mesh = jax.make_mesh((R, 1), ("data", "model"))
+        mesh = make_mesh((R, 1), ("data", "model"))
         layout = ServeLayout(batch_axes=("data",), pool_axes=("data",))
         prompts = [list(rng.integers(0, cfg.vocab_size, size=12))
                    for _ in range(PER_RANK_REQS * R)]
@@ -90,6 +91,9 @@ def main():
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                         " --xla_force_host_platform_device_count=4")
+    # The worker measures a CPU virtual-device mesh; it must never reach
+    # for an accelerator the parent process may already hold.
+    env["JAX_PLATFORMS"] = "cpu"
     env.setdefault("PYTHONPATH", os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
         "src"))

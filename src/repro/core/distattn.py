@@ -82,7 +82,7 @@ def distattn_decode_paged(
     *,
     scale: float | None = None,
     backend: str = "xla",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Full paged DistAttention decode step for one layer, inside shard_map.
 
@@ -94,7 +94,8 @@ def distattn_decode_paged(
         from repro.kernels.ops import paged_micro_attention
         o, m, l = paged_micro_attention(q, pool_k, pool_v, local_table,
                                         last_block_len, scale=scale,
-                                        interpret=interpret)
+                                        interpret=interpret,
+                                        backend="pallas")
     else:
         k, v = gather_local_kv(pool_k, pool_v, local_table)
         mask = local_mask_from_table(local_table, bs, last_block_len)

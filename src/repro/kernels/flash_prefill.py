@@ -6,6 +6,9 @@ tiles; fp32 accumulators in VMEM scratch.
 TPU mapping:
   grid = (B, H, nq, nk) with nk innermost/sequential; q tile (bq, D) and
   KV tile (bk, D) are MXU-shaped (128 x 128-padded-D by default).
+  Operands are head-major [B, H, S, D] (the ops.py wrapper transposes)
+  so each tile is a (1, 1, bq, D) block whose last two dims Mosaic can
+  tile; a (1, bq, 1, D) block of [B, S, H, D] is refused.
   GQA: the kv-head block index is h // (H // K) — computed in the
   BlockSpec index map, so each query head streams only its group's KV.
   Causal skip: tiles entirely above the diagonal (and entirely outside
@@ -44,9 +47,9 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s,
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)            # [bq, D]
-        k = k_ref[0, :, 0, :].astype(jnp.float32)            # [bk, D]
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32)                  # [bq, D]
+        k = k_ref[0, 0].astype(jnp.float32)                  # [bk, D]
+        v = v_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         qp = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
@@ -71,12 +74,12 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc, m_s, l_s,
     def _finalize():
         l = l_s[:, 0]
         denom = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc[...] / denom[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc[...] / denom[:, None]).astype(o_ref.dtype)
 
 
 def flash_prefill_kernel(
-    q: jax.Array,          # [B, S, H, D] (S and D pre-padded by ops.py)
-    k: jax.Array,          # [B, S, K, D]
+    q: jax.Array,          # [B, H, S, D] (S and D pre-padded by ops.py)
+    k: jax.Array,          # [B, K, S, D]
     v: jax.Array,
     *,
     seq: int,              # true (unpadded) sequence length
@@ -84,10 +87,10 @@ def flash_prefill_kernel(
     window: int = 0,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ):
-    B, S, H, D = q.shape
-    K = k.shape[2]
+    B, H, S, D = q.shape
+    K = k.shape[1]
     G = H // K
     bq = min(bq, S)
     bk = min(bk, S)
@@ -99,19 +102,19 @@ def flash_prefill_kernel(
         kernel,
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, iq, ik: (b, iq, h, 0)),
-            pl.BlockSpec((1, bk, 1, D),
-                         lambda b, h, iq, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, bk, 1, D),
-                         lambda b, h, iq, ik: (b, ik, h // G, 0)),
+            pl.BlockSpec((1, 1, bq, D), lambda b, h, iq, ik: (b, h, iq, 0)),
+            pl.BlockSpec((1, 1, bk, D),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec((1, 1, bk, D),
+                         lambda b, h, iq, ik: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, D),
-                               lambda b, h, iq, ik: (b, iq, h, 0)),
+        out_specs=pl.BlockSpec((1, 1, bq, D),
+                               lambda b, h, iq, ik: (b, h, iq, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        out_shape=jax.ShapeDtypeStruct((B, S, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
         interpret=interpret,
     )(q, k, v)

@@ -12,10 +12,14 @@ TPU mapping:
   BlockSpec prefetches pool block ``table[r, j]`` directly from HBM into
   VMEM — the kernel never touches blocks that are not in the table (and
   ``pl.when`` skips -1 slots entirely).
-  Tiles: KV block (bs, D) with bs=block_size (128 default) and D padded
-  to a lane multiple of 128 by the ops.py wrapper — (q @ k^T) is a
-  [G, D] x [D, bs] MXU matmul per kv-head group, (p @ v) is [G, bs] x
-  [bs, D]. fp32 accumulation throughout.
+  Tiles: KV block (bs, D) with bs=block_size and D padded to a lane
+  multiple of 128 by the ops.py wrapper — (q @ k^T) is a [G, D] x
+  [D, bs] MXU matmul per kv-head group, (p @ v) is [G, bs] x [bs, D].
+  fp32 accumulation throughout.
+  The per-head statistics m and l leave the kernel as [R, 1, H] with
+  (1, 1, H) blocks: Mosaic requires a block's last two dims to be
+  multiples of (8, 128) or equal to the array's, and a (1, H) block of
+  an [R, H] array is neither.
 """
 from __future__ import annotations
 
@@ -79,8 +83,8 @@ def _kernel(table_ref, nblk_ref, tail_ref,          # scalar prefetch (SMEM)
     @pl.when(j == mb - 1)
     def _finalize():
         o_ref[0] = acc[...]
-        m_ref[0] = m_s[0]
-        l_ref[0] = l_s[0]
+        m_ref[0] = m_s[...]
+        l_ref[0] = l_s[...]
 
 
 def paged_micro_attention_kernel(
@@ -92,8 +96,9 @@ def paged_micro_attention_kernel(
     tail_len: jax.Array,   # [R] int32 valid tokens in last local slot
     *,
     scale: float,
-    interpret: bool = True,
+    interpret: bool,
 ):
+    """Returns (o [R, H, D], m [R, 1, H], l [R, 1, H]), all float32."""
     R, H, D = q.shape
     NB, bs, K, _ = pool_k.shape
     MB = table.shape[1]
@@ -113,8 +118,8 @@ def paged_micro_attention_kernel(
         ],
         out_specs=[
             pl.BlockSpec((1, H, D), lambda r, j, t, n, tl: (r, 0, 0)),
-            pl.BlockSpec((1, H), lambda r, j, t, n, tl: (r, 0)),
-            pl.BlockSpec((1, H), lambda r, j, t, n, tl: (r, 0)),
+            pl.BlockSpec((1, 1, H), lambda r, j, t, n, tl: (r, 0, 0)),
+            pl.BlockSpec((1, 1, H), lambda r, j, t, n, tl: (r, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((H, D), jnp.float32),
@@ -128,8 +133,8 @@ def paged_micro_attention_kernel(
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((R, H, D), jnp.float32),
-            jax.ShapeDtypeStruct((R, H), jnp.float32),
-            jax.ShapeDtypeStruct((R, H), jnp.float32),
+            jax.ShapeDtypeStruct((R, 1, H), jnp.float32),
+            jax.ShapeDtypeStruct((R, 1, H), jnp.float32),
         ],
         interpret=interpret,
     )(table, nblk, tail_len, q, pool_k, pool_v)

@@ -96,7 +96,7 @@ def paged_prefill_micro_attention_kernel(
     *,
     num_kv_heads: int,
     scale: float,
-    interpret: bool = True,
+    interpret: bool,
 ):
     KCG, D = q.shape
     NB, bs, K, _ = pool_k.shape

@@ -2,12 +2,16 @@
 
 Head dim is padded to a 128-lane multiple (zero-padding leaves q.k and
 p.v unchanged, the softmax scale always uses the TRUE head dim), sequence
-to the tile size. ``interpret`` defaults to True off-TPU so the same code
-validates on CPU and compiles natively on TPU.
+to the tile size. This module is the one place that resolves an unset
+``interpret`` or attention ``backend`` from the process's default
+backend (``_on_tpu``): the kernels themselves take ``interpret`` as a
+required keyword, so the same code validates on CPU in interpret mode
+and compiles natively on TPU, and never silently interprets on a chip.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +24,18 @@ from repro.kernels.micro_attn_prefill import \
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    return (not _on_tpu()) if interpret is None else interpret
+
+
+def resolve_backend(backend: Optional[str]) -> str:
+    """Paged-attention backend of a serving step: ``backend`` if given,
+    else the Pallas kernels on a TPU and the jnp gather elsewhere."""
+    if backend is not None:
+        return backend
+    return "pallas" if _on_tpu() else "jnp"
 
 
 def _pad_last(x, mult):
@@ -44,18 +60,22 @@ def _pad_axis(x, axis, mult):
                                              "interpret"))
 def flash_prefill(q, k, v, *, scale=None, window=0, bq=128, bk=128,
                   interpret=None):
-    """Causal flash attention. q [B,S,H,D], k/v [B,S,K,D] -> [B,S,H,D]."""
+    """Causal flash attention. q [B,S,H,D], k/v [B,S,K,D] -> [B,S,H,D].
+
+    The kernel runs head-major: operands are transposed to [B, H, S, D]
+    (and back) so every tile is a Mosaic-aligned (bq, D) slab.
+    """
     B, S, H, D = q.shape
     if scale is None:
         scale = D ** -0.5
-    if interpret is None:
-        interpret = not _on_tpu()
-    qp = _pad_axis(_pad_last(q, 128), 1, bq)
-    kp = _pad_axis(_pad_last(k, 128), 1, bq)
-    vp = _pad_axis(_pad_last(v, 128), 1, bq)
-    out = flash_prefill_kernel(qp, kp, vp, seq=S, scale=scale, window=window,
-                               bq=bq, bk=bk, interpret=interpret)
-    return out[:, :S, :, :D]
+
+    def head_major(x):
+        return _pad_axis(_pad_last(x, 128), 1, bq).transpose(0, 2, 1, 3)
+
+    out = flash_prefill_kernel(head_major(q), head_major(k), head_major(v),
+                               seq=S, scale=scale, window=window, bq=bq,
+                               bk=bk, interpret=_interpret(interpret))
+    return out.transpose(0, 2, 1, 3)[:, :S, :, :D]
 
 
 def paged_micro_attention_jnp(q, pool_k, pool_v, table, tail_len, *,
@@ -121,8 +141,6 @@ def paged_prefill_attention(q, pool_k, pool_v, table, tail_len, *,
     if backend == "jnp":
         return paged_prefill_attention_jnp(q, pool_k, pool_v, table,
                                            tail_len, scale=scale)
-    if interpret is None:
-        interpret = not _on_tpu()
     G = H // K
     # kv-head-major query layout: each head group is a contiguous
     # [C*G, D] slab the kernel feeds to the MXU; rows padded to a
@@ -136,7 +154,7 @@ def paged_prefill_attention(q, pool_k, pool_v, table, tail_len, *,
     nblk = jnp.sum(table >= 0)[None].astype(jnp.int32)
     o, m, l = paged_prefill_micro_attention_kernel(
         qp, kp, vp, table, nblk, tail_len[None], num_kv_heads=K,
-        scale=scale, interpret=interpret)
+        scale=scale, interpret=_interpret(interpret))
     o = o.reshape(K, CGp, -1)[:, :C * G, :D]
     m = m.reshape(K, CGp)[:, :C * G]
     l = l.reshape(K, CGp)[:, :C * G]
@@ -167,16 +185,15 @@ def paged_micro_attention(q, pool_k, pool_v, table, tail_len, *,
                                          table.astype(jnp.int32),
                                          tail_len.astype(jnp.int32),
                                          scale=scale)
-    if interpret is None:
-        interpret = not _on_tpu()
     nblk = jnp.sum(table >= 0, axis=1).astype(jnp.int32)
     qp = _pad_last(q, 128)
     kp = _pad_last(pool_k, 128)
     vp = _pad_last(pool_v, 128)
     o, m, l = paged_micro_attention_kernel(
         qp, kp, vp, table.astype(jnp.int32), nblk,
-        tail_len.astype(jnp.int32), scale=scale, interpret=interpret)
-    return o[:, :, :D], m, l
+        tail_len.astype(jnp.int32), scale=scale,
+        interpret=_interpret(interpret))
+    return o[:, :, :D], m[:, 0], l[:, 0]
 
 
 def paged_micro_attention_ranks(q, pools_k, pools_v, tables, tails, *,

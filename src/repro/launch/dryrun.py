@@ -49,7 +49,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
         donate_argnums=donate)
 
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(*[cell.kwargs[n] for n in argnames])
         compiled = lowered.compile()
     t1 = time.time()

@@ -36,10 +36,12 @@ def main():
     import jax
     import numpy as np
     from repro.configs import get_smoke_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.model import init_params
     from repro.serving import (Arrival, LLMServer, SamplingParams,
                                ServingConfig)
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch)
     params = init_params(jax.random.PRNGKey(0), cfg)
     server = LLMServer(params, cfg,
@@ -59,7 +61,8 @@ def main():
     dt = time.time() - t0
     st = server.cluster.throughput_stats
     print(f"{stats['finished']:.0f}/{len(arrivals)} finished, "
-          f"{stats['tokens']:.0f} tokens ({dt:.1f}s wall on CPU); "
+          f"{stats['tokens']:.0f} tokens ({dt:.1f}s wall on "
+          f"{jax.devices()[0].platform}); "
           f"ttft_p50={stats['ttft_p50'] * 1e3:.0f}ms "
           f"ttft_p99={stats['ttft_p99'] * 1e3:.0f}ms "
           f"tbt_p99={stats['tbt_p99'] * 1e3:.0f}ms")

@@ -6,7 +6,7 @@ decode (paged DistAttention with collective merge).
 """
 from __future__ import annotations
 
-from typing import Callable, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -66,13 +66,14 @@ def apply_attention_train(
 
 def make_causal_core(cfg: ModelConfig, *, backend: str = "xla",
                      window: int = 0, chunk: int = 512,
-                     interpret: bool = True,
+                     interpret: Optional[bool] = None,
                      acc_constraint=None) -> AttnCore:
     """Build the training/prefill attention core.
 
     backend "xla": chunked online-softmax in pure jnp (memory-bounded,
     scan over KV chunks — the lowering used for dry-runs).
-    backend "pallas": the flash-prefill kernel (interpret=True on CPU).
+    backend "pallas": the flash-prefill kernel; ``interpret`` None lets
+    ``kernels.ops`` pick (interpret mode off-TPU, native on a TPU).
     backend "ref": naive full-matrix reference (tests/tiny shapes only).
 
     ``acc_constraint``: optional fn((o, m, l)) -> (o, m, l) applied to the

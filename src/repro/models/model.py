@@ -172,7 +172,7 @@ def unembed(params, cfg: ModelConfig, x):
 
 def forward(params, cfg: ModelConfig, tokens=None, embeds=None, *,
             backend: str = "xla", chunk: int = 512,
-            capacity_factor: float = 1.25, interpret: bool = True,
+            capacity_factor: float = 1.25, interpret=None,
             remat: bool = False, ep_groups: int = 0,
             layer_constraints=None) -> Tuple[jax.Array, jax.Array]:
     """Full-sequence causal forward. Returns (logits [B,T,V], moe_aux).

@@ -38,6 +38,7 @@ from repro.configs.base import ModelConfig
 from repro.core.online_softmax import (combine, finalize,
                                        micro_attention_decode,
                                        micro_attention_prefill)
+from repro.kernels.ops import resolve_backend
 from repro.models.attention import make_causal_core, qkv_project
 from repro.models.common import apply_ffn, apply_norm
 from repro.models.model import (DecodeState, _attn_layer_fwd, _rglru_layer_fwd,
@@ -461,8 +462,7 @@ def decode_step_paged(params, cfg: ModelConfig, tokens, lens,
     Returns (logits [B, V], new_pool_k, new_pool_v).
     """
     assert cfg.family in ("dense", "moe"), "only attention archs pool KV"
-    if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    backend = resolve_backend(backend)
     remote_k = tuple(pk for pk, _ in remote_pools)
     remote_v = tuple(pv for _, pv in remote_pools)
     return _decode_step_paged_jit(
@@ -581,8 +581,7 @@ def prefill_chunk_paged(params, cfg: ModelConfig, tokens, t0: int,
     what the engine streams to creditor pools for prefix rows.
     """
     assert cfg.family in ("dense", "moe"), "only attention archs pool KV"
-    if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    backend = resolve_backend(backend)
     C = len(tokens)
     positions = t0 + jnp.arange(C, dtype=jnp.int32)[None]
     valid = (jnp.arange(C, dtype=jnp.int32) < n_valid)[None]

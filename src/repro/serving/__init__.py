@@ -80,7 +80,7 @@ Mesh-sharded global KV pool (opt-in)
 into ONE cluster-wide ``GlobalKVPool`` array ``[ranks, L, NB, bs, K,
 hd]`` whose rank axis can be sharded over a device mesh:
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))   # repro.launch.mesh
     layout = ServeLayout(batch_axes=("data",), pool_axes=("data",))
     server = LLMServer(params, cfg,
                        ServingConfig.v5e(global_pool=True),

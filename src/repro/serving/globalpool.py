@@ -95,18 +95,21 @@ class GlobalKVPool:
         self.mesh = mesh
         self.pool_axes = tuple(pool_axes)
         L, K, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-        dt = jnp.dtype(cfg.dtype)
-        self.k = jnp.zeros((n_ranks, L, num_blocks, block_size, K, hd), dt)
-        self.v = jnp.zeros((n_ranks, L, num_blocks, block_size, K, hd), dt)
-        if mesh is not None:
+        shape = (n_ranks, L, num_blocks, block_size, K, hd)
+        zeros = functools.partial(jnp.zeros, shape, jnp.dtype(cfg.dtype))
+        if mesh is None:
+            self.k, self.v = zeros(), zeros()
+        else:
             n_shards = 1
             for ax in self.pool_axes:
                 n_shards *= mesh.shape[ax]
             assert n_ranks % n_shards == 0, \
                 f"{n_ranks} ranks not divisible over {n_shards} shards"
-            sh = NamedSharding(mesh, P(self.pool_axes))
-            self.k = jax.device_put(self.k, sh)
-            self.v = jax.device_put(self.v, sh)
+            # Created in place, shard by shard: no device ever holds the
+            # whole cluster's pool (at four chips' worth it cannot fit).
+            make = jax.jit(zeros, out_shardings=NamedSharding(
+                mesh, P(self.pool_axes)))
+            self.k, self.v = make(), make()
         # THE shared allocator view: engine i's RManager aliases
         # ranks[i], so host-side placement metadata is identical whether
         # the step runs in-process or under shard_map.

@@ -44,6 +44,7 @@ from repro.core.online_softmax import (combine, finalize,
                                        merge_partials_collective,
                                        micro_attention_decode,
                                        micro_attention_prefill)
+from repro.kernels.ops import resolve_backend
 from repro.kernels.ref import paged_micro_attention_ref
 from repro.models.attention import make_causal_core, qkv_project
 from repro.models.common import apply_ffn, apply_norm
@@ -524,14 +525,8 @@ def serve_decode_step_state(params, cfg: ModelConfig, layout: ServeLayout,
 # shard_mapped with collective LSE-merges (paper Eq. 3) under a mesh.
 # --------------------------------------------------------------------- #
 def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map (0.5+, check_vma) or the experimental module
-    (0.4.x, check_rep) — whichever this jax provides."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # Incremented once per trace of a global-pool jit; serving tests assert
@@ -733,8 +728,7 @@ def decode_step_global(params, cfg: ModelConfig, tokens, lens, gk, gv,
     Queries broadcast; KV never moves. Returns (logits, gk, gv).
     """
     assert cfg.family in ("dense", "moe"), "only attention archs pool KV"
-    if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    backend = resolve_backend(backend)
     return _decode_step_global_jit(
         params, jnp.asarray(tokens, jnp.int32),
         jnp.asarray(lens, jnp.int32), gk, gv,
@@ -812,8 +806,7 @@ def prefill_chunk_global(params, cfg: ModelConfig, tokens, t0: int,
     k_chunk [L, C, K, hd], v_chunk).
     """
     assert cfg.family in ("dense", "moe"), "only attention archs pool KV"
-    if backend is None:
-        backend = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    backend = resolve_backend(backend)
     C = len(tokens)
     positions = t0 + jnp.arange(C, dtype=jnp.int32)[None]
     valid = (jnp.arange(C, dtype=jnp.int32) < n_valid)[None]

@@ -36,8 +36,6 @@ def make_grad_sync(mesh, axis_name: str = "pod"):
     Gradients are assumed replicated within a pod (post data-axis psum)
     and DIFFERENT across pods; output is the pod-averaged gradient.
     """
-    from jax.experimental.shard_map import shard_map
-
     def spec_for(g):
         return P(axis_name, *([None] * (g.ndim)))    # stacked per pod
 
@@ -50,7 +48,7 @@ def make_grad_sync(mesh, axis_name: str = "pod"):
             return jax.tree.map(
                 lambda g: _sync_one(g[0], axis_name)[None], gl)
 
-        return shard_map(body, mesh=mesh, in_specs=(in_specs,),
-                         out_specs=out_specs)(stacked_grads)
+        return jax.shard_map(body, mesh=mesh, in_specs=(in_specs,),
+                             out_specs=out_specs)(stacked_grads)
 
     return jax.jit(sync)

@@ -4,7 +4,10 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
 """Subprocess helper: the mesh-sharded GLOBAL KV pool must generate the
 same greedy tokens as the per-instance cluster AND the dense-cache
 oracle, dense + moe, with a mid-stream StripedMove relocating blocks
-between rank slices of the one pool tensor. Exit 0 on success."""
+between rank slices of the one pool tensor. Exit 0 on success.
+
+The mesh-vs-one-device half is ``repro.launch.identity``, the same check
+``chip_smoke.py --chips 4`` makes on four TPU chips."""
 import dataclasses
 import sys
 
@@ -16,10 +19,11 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..",
                                 "src"))
 
 from repro.configs import get_smoke_config
+from repro.launch.identity import mesh_matches_one_device, serve_greedy
+from repro.launch.mesh import make_mesh
 from repro.models.model import decode_step, init_params
 from repro.models.prefill import prefill
-from repro.serving import (Cluster, Request, SamplingParams,
-                           ServingConfig)
+from repro.serving import ServingConfig
 from repro.serving.sharded_step import ServeLayout
 
 
@@ -33,24 +37,6 @@ def greedy_ref(params, cfg, prompt, n_new):
                                 jnp.asarray([out[-1]], jnp.int32))
         out.append(int(jnp.argmax(lg[0])))
     return out
-
-
-def run_cluster(params, cfg, prompts, n_new, *, n_inst, global_pool,
-                mesh=None, layout=None):
-    cl = Cluster(params, cfg,
-                 ServingConfig.smoke(n_instances=n_inst, max_batch=2,
-                                     pool_blocks=32,
-                                     global_pool=global_pool),
-                 mesh=mesh, layout=layout)
-    reqs = [Request(prompt=p, sampling=SamplingParams(max_new_tokens=n_new))
-            for p in prompts]
-    for r in reqs:
-        cl.submit(r)
-    cl.run_until_done(max_steps=400)
-    assert all(r.done for r in reqs), [r.state for r in reqs]
-    moved = sum(e.stats.kv_moved for e in cl.engines.values())
-    copies = sum(e.stats.pool_copy_steps for e in cl.engines.values())
-    return [r.output for r in reqs], moved, copies
 
 
 def check(arch, n_inst, pool_axes, mesh_shape):
@@ -67,24 +53,20 @@ def check(arch, n_inst, pool_axes, mesh_shape):
     n_new = 12
     refs = [greedy_ref(params, cfg, p, n_new) for p in prompts]
 
-    base, moved, _ = run_cluster(params, cfg, prompts, n_new,
-                                 n_inst=n_inst, global_pool=False)
-    assert base == refs, f"{arch}: per-instance cluster vs oracle"
-    assert moved > 0, f"{arch}: expected mid-stream KV movement"
+    config = ServingConfig.smoke(n_instances=n_inst, max_batch=2,
+                                 pool_blocks=32)
+    base = serve_greedy(params, cfg, config, prompts, n_new, max_steps=400)
+    assert base.outputs == refs, f"{arch}: per-instance cluster vs oracle"
+    assert base.kv_moved > 0, f"{arch}: expected mid-stream KV movement"
 
-    outs, moved, copies = run_cluster(params, cfg, prompts, n_new,
-                                      n_inst=n_inst, global_pool=True)
-    assert outs == refs, f"{arch}: global pool (vmap) vs oracle"
-    assert moved > 0
-    assert copies == 0, f"{arch}: global-pool donation broken ({copies})"
-
-    mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+    mesh = make_mesh(mesh_shape, ("data", "model"))
     layout = ServeLayout(batch_axes=("data",), pool_axes=pool_axes)
-    outs, moved, _ = run_cluster(params, cfg, prompts, n_new,
-                                 n_inst=n_inst, global_pool=True,
-                                 mesh=mesh, layout=layout)
-    assert outs == refs, f"{arch}: global pool (shard_map) vs oracle"
-    assert moved > 0
+    on_mesh, one_dev = mesh_matches_one_device(params, cfg, config, prompts,
+                                               n_new, mesh, layout)
+    assert one_dev.outputs == refs, f"{arch}: global pool (vmap) vs oracle"
+    assert one_dev.kv_moved > 0 and on_mesh.kv_moved > 0
+    assert one_dev.pool_copy_steps == 0, \
+        f"{arch}: global-pool donation broken ({one_dev.pool_copy_steps})"
     print(f"OK {arch} n_inst={n_inst} pool_axes={pool_axes} "
           f"mesh={mesh_shape}")
 

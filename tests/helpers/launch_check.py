@@ -15,11 +15,12 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "..",
                                 "src"))
 
 from repro.launch.inputs import build_cell           # noqa: E402
+from repro.launch.mesh import make_mesh              # noqa: E402
 from repro.launch.roofline import collective_bytes_from_hlo  # noqa: E402
 
 
 def check_cells():
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     cells = [("qwen3-0.6b", "train_4k"), ("qwen3-0.6b", "prefill_32k"),
              ("qwen3-0.6b", "decode_32k"), ("xlstm-350m", "decode_32k"),
              ("recurrentgemma-9b", "prefill_32k")]
@@ -30,7 +31,7 @@ def check_cells():
                          in_shardings=tuple(cell.in_shardings.get(n)
                                             for n in names),
                          out_shardings=cell.out_shardings)
-        with mesh:
+        with jax.set_mesh(mesh):
             compiled = jitted.lower(
                 *[cell.kwargs[n] for n in names]).compile()
         coll = collective_bytes_from_hlo(compiled.as_text())
@@ -40,31 +41,25 @@ def check_cells():
 
 
 def _shard_map(f, mesh, in_specs, out_specs):
-    """jax.shard_map (0.5+, check_vma) or the experimental module
-    (0.4.x, check_rep) — whichever this jax provides."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def check_grad_sync():
     from repro.training.grad_sync import _sync_one
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",))
     g = np.random.default_rng(0).normal(size=(4, 32, 16)).astype(np.float32)
 
     fn = jax.jit(_shard_map(
         lambda x: _sync_one(x[0], "pod")[None],
         mesh=mesh, in_specs=P("pod"), out_specs=P("pod")))
-    with mesh:
+    with jax.set_mesh(mesh):
         out = np.asarray(fn(jnp.asarray(g)))
     want = g.mean(axis=0)
     for i in range(4):
         np.testing.assert_allclose(out[i], want, atol=2e-2)
     # int8 all-gather must appear in the lowered HLO (wire-level claim).
-    with mesh:
+    with jax.set_mesh(mesh):
         txt = jax.jit(_shard_map(
             lambda x: _sync_one(x[0], "pod")[None], mesh=mesh,
             in_specs=P("pod"), out_specs=P("pod"))

@@ -19,13 +19,14 @@ from repro.models.model import decode_step, init_params
 from repro.models.prefill import prefill
 from repro.serving.sharded_step import ServeLayout, serve_decode_step
 from repro.distributed.sharding import param_specs, validate_divisibility
+from repro.launch.mesh import make_mesh
 
 
 def check(arch: str, pool_axes, rng_seed=0, variant="baseline"):
     cfg = get_smoke_config(arch)
     key = jax.random.PRNGKey(rng_seed)
     params = init_params(key, cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     R, T = 4, 21                       # ragged: partial tail block
     bs = 8
     tokens_hist = jax.random.randint(key, (R, T), 0, cfg.vocab_size)
@@ -95,12 +96,15 @@ def check(arch: str, pool_axes, rng_seed=0, variant="baseline"):
             pool_spec, pool_spec, itab, itab, itab, itab, itab, bsh, bsh),
     )
     dt = jnp.dtype(cfg.dtype)
-    with mesh:
-        logits, pk_new, pv_new = jitted(
-            params, jnp.asarray(pool_k, dt), jnp.asarray(pool_v, dt),
+    # Inputs are made before the mesh is set: under ``jax.set_mesh``
+    # fresh arrays are committed replicated on it, which jit will not
+    # silently reshard to the in_shardings above.
+    args = (params, jnp.asarray(pool_k, dt), jnp.asarray(pool_v, dt),
             jnp.asarray(tables), jnp.asarray(nblk), jnp.asarray(tails),
             jnp.asarray(wblk), jnp.asarray(woff),
             new_tok, jnp.full((R,), T, jnp.int32))
+    with jax.set_mesh(mesh):
+        logits, pk_new, pv_new = jitted(*args)
 
     got = np.asarray(logits, np.float32)
     want = np.asarray(ref_logits, np.float32)

@@ -15,12 +15,20 @@ pytest.importorskip("hypothesis", reason="property tests need hypothesis "
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models.model import init_params
 from repro.models.moe import apply_moe
 
 
 def _mesh11():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return jax.set_mesh(make_mesh((1, 1), ("data", "model")))
+
+
+def _grouped(lp, x, cfg, ep_groups):
+    """No-drop EP-grouped MoE, jitted: its bare-spec sharding pins
+    resolve against the mesh set in context only inside a trace."""
+    return jax.jit(lambda lp, x: apply_moe(lp, x, cfg, capacity_factor=-1.0,
+                                           ep_groups=ep_groups))(lp, x)
 
 
 @pytest.mark.parametrize("arch", ["qwen2-moe-a2.7b", "kimi-k2-1t-a32b"])
@@ -33,7 +41,7 @@ def test_ep_grouped_equals_plain(arch):
                           jnp.float32).astype(cfg.dtype)
     with _mesh11():
         plain = apply_moe(lp, x, cfg, capacity_factor=-1.0, ep_groups=0)
-        grouped = apply_moe(lp, x, cfg, capacity_factor=-1.0, ep_groups=4)
+        grouped = _grouped(lp, x, cfg, ep_groups=4)
     np.testing.assert_allclose(np.asarray(plain, np.float32),
                                np.asarray(grouped, np.float32),
                                atol=3e-2, rtol=3e-2)
@@ -50,8 +58,7 @@ def test_ep_grouped_equivalence_property(groups, seed):
                           jnp.float32).astype(cfg.dtype)
     with _mesh11():
         plain = apply_moe(lp, x, cfg, capacity_factor=-1.0, ep_groups=0)
-        grouped = apply_moe(lp, x, cfg, capacity_factor=-1.0,
-                            ep_groups=groups)
+        grouped = _grouped(lp, x, cfg, ep_groups=groups)
     np.testing.assert_allclose(np.asarray(plain, np.float32),
                                np.asarray(grouped, np.float32),
                                atol=3e-2, rtol=3e-2)
