@@ -130,6 +130,18 @@ surfaces ``dead_instances`` / ``fault_recoveries`` /
 ``FaultPolicy`` (see ``docs/ARCHITECTURE.md``); ``bench_chaos`` gates
 recovery identity and goodput-under-crash in CI.
 
+Observability (always on)
+-------------------------
+The serving loop opens named trace spans (``serve.step``,
+``serve.engine``, ``serve.admit``, ``serve.readback``, ...; the list is
+``repro.serving.tracing.NAMES``) at each layer boundary. A profile
+taken around serving (``jax.profiler.trace``) holds them on the device
+trace's clock, so every idle gap of the device is named by the host
+work under it; with no profile running they only add to running
+totals, which ``server.metrics`` reports as ``trace.<name>.s`` and
+``trace.<name>.n``. ``Request.admitted_at`` stamps when admission
+first started, so queue wait is ``admitted_at - arrival_time``.
+
 Internal layers (exported for tests/benchmarks, not the serving API)
 --------------------------------------------------------------------
 ``Cluster`` executes steps: N ``InstanceEngine``s (each owning a
@@ -158,6 +170,7 @@ from repro.serving.rmanager import RManager
 from repro.serving.scheduler import (GreedyScheduler, InstanceView,
                                      SpanLeg, StripedMove)
 from repro.serving.server import Arrival, LLMServer, RequestHandle
+from repro.serving.tracing import Tracer
 
 __all__ = [
     "LLMServer", "RequestHandle", "Arrival", "ServingConfig",
@@ -168,5 +181,5 @@ __all__ = [
     "InstanceView", "SpanLeg", "StripedMove", "HostKVTier",
     "RadixPrefixCache", "GlobalKVPool",
     "FaultPolicy", "FaultPlan", "FaultEvent", "FaultInjector",
-    "FaultStats", "TransferError", "FrameCorruptionError",
+    "FaultStats", "TransferError", "FrameCorruptionError", "Tracer",
 ]

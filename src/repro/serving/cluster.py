@@ -69,6 +69,7 @@ from repro.serving.prefixcache import RadixPrefixCache
 from repro.serving.protocol import MoveKVCache, MoveLeg, MoveResult
 from repro.serving.request import Request, RequestState
 from repro.serving.staging import AsyncStager
+from repro.serving.tracing import Tracer
 
 
 def reserve_all_or_nothing(req_id: int, legs) -> bool:
@@ -207,6 +208,8 @@ class Cluster:
         self.block_size = config.block_size
         self.move_chunk = config.move_chunk_tokens
         self.schedule_every = config.schedule_every
+        # One set of trace-span totals for the whole serving stack.
+        self.tracer = Tracer()
         # All stripe/offload/reclaim row copies and streaming-prefill
         # creditor writes go through one double-buffered stager:
         # async_movement=True overlaps them with decode compute,
@@ -215,7 +218,8 @@ class Cluster:
         self.stager = AsyncStager(overlap=config.async_movement,
                                   max_retries=fpol.max_transfer_retries,
                                   backoff_base_s=fpol.retry_backoff_base_s,
-                                  backoff_max_s=fpol.retry_backoff_max_s)
+                                  backoff_max_s=fpol.retry_backoff_max_s,
+                                  tracer=self.tracer)
         # Global-pool mode: ONE [n_instances, L, NB, bs, K, hd] tensor
         # holds every instance's KV (optionally sharded over ``mesh``
         # per ``layout.pool_axes``); every engine aliases its rank's
@@ -245,7 +249,7 @@ class Cluster:
                               pool_blocks=config.pool_blocks,
                               block_size=config.block_size, inst_id=i,
                               prefill_chunk=config.prefill_chunk,
-                              gpool=self.gpool)
+                              gpool=self.gpool, tracer=self.tracer)
             for i in range(config.n_instances)
         }
         for eng in self.engines.values():
@@ -262,7 +266,8 @@ class Cluster:
                 verify=fpol.verify_host_frames,
                 max_retries=fpol.max_transfer_retries,
                 backoff_base_s=fpol.retry_backoff_base_s,
-                backoff_max_s=fpol.retry_backoff_max_s)
+                backoff_max_s=fpol.retry_backoff_max_s,
+                tracer=self.tracer)
         if config.prefix_cache:
             self.prefix_cache = RadixPrefixCache(self,
                                                  host_tier=self.host_tier)
@@ -440,6 +445,14 @@ class Cluster:
         return sink
 
     def _execute_move(self, mv: MoveKVCache) -> MoveResult:
+        """Execute one striped plan under its ``serve.move`` trace span
+        (see ``_move``)."""
+        tokens = self.block_size * sum(leg.num_blocks for leg in mv.legs)
+        with self.tracer.span("serve.move", req=mv.req_id,
+                              legs=len(mv.legs), tokens=tokens):
+            return self._move(mv)
+
+    def _move(self, mv: MoveKVCache) -> MoveResult:
         """Execute one striped plan: the oldest blocks of a request's
         span on ``src_inst`` stream onto one or more destinations.
 
@@ -559,7 +572,7 @@ class Cluster:
                 if dst_id not in insts:
                     insts.append(dst_id)
             src.stats.kv_moved += nbytes
-            src.stats.tokens_moved_steps.append(n * bs)
+            src.stats.moves += 1
             # Rewrite the chain entries in place (ID-based: the moved
             # blocks keep their position in the global token order).
             chain = owner.req_chain.get(mv.req_id)
@@ -581,7 +594,7 @@ class Cluster:
             alt = self._pick_creditor(
                 exclude={mv.src_inst} | {d for d, _ in failed_tail})
             if alt is not None:
-                res = self._execute_move(MoveKVCache(
+                res = self._move(MoveKVCache(
                     mv.req_id, mv.src_inst, [MoveLeg(alt, n_rest)]))
                 if res == MoveResult.OK:
                     self.fault_stats.move_leg_replans += 1
@@ -746,7 +759,7 @@ class Cluster:
             max_local_len=ref.max_local_len,
             pool_blocks=ref.rmanager.pool.alloc.num_blocks,
             block_size=self.block_size, inst_id=new_id,
-            prefill_chunk=ref.prefill_chunk)
+            prefill_chunk=ref.prefill_chunk, tracer=self.tracer)
         self.engines[new_id].prefix_sink = self._make_prefix_sink(new_id)
         self.engines[new_id].peers = self.engines
         if self.prefix_cache is not None:
@@ -760,6 +773,47 @@ class Cluster:
         now = time.monotonic() if now is None else now
         self._step_count += 1
 
+        tr = self.tracer
+        with tr.span("serve.heartbeat"):
+            self._heartbeats(now)
+
+        # Reactive overflow shipping, then periodic Algorithm-1 planning.
+        self._reactive_moves()
+        if self._step_count % self.schedule_every == 0:
+            with tr.span("serve.plan"):
+                # Frontend lifecycle feeds the planner: per-request
+                # urgency (priority + deadline proximity) biases which
+                # debtor requests are offloaded first, so near-deadline
+                # requests get their memory relief before best-effort
+                # ones.
+                urgency = {rid: r.urgency(now)
+                           for rid, r in self.requests.items()
+                           if not r.done and (r.priority
+                                              or r.deadline_s is not None)}
+                moves = self.gmanager.plan_moves(urgency=urgency)
+            for mv in moves:
+                self._execute_move(mv)
+
+        # Resume parked (preempted) requests before the decode sweep so
+        # a freed slot carries tokens this very step; the preemptor's
+        # guards keep it from stealing capacity the waiting queue (or a
+        # more urgent arrival) is entitled to.
+        if self.preemptor is not None:
+            self.preemptor.maybe_resume(now=now)
+
+        made = 0
+        for i, eng in self.engines.items():
+            if i in self._dead:
+                continue
+            with tr.span("serve.engine", inst=i):
+                made += eng.step()
+        with tr.span("serve.drain"):
+            self._drain()
+        return made
+
+    def _heartbeats(self, now: float) -> None:
+        """Fire armed chaos events, collect every live instance's
+        heartbeat, and quarantine instances found dead."""
         # Armed chaos events fire first: a crash injected at this step
         # already misses this step's heartbeat, exactly like a real
         # failure in the gap between steps.
@@ -793,32 +847,9 @@ class Cluster:
         if dead:
             self._handle_dead(dead)
 
-        # Reactive overflow shipping, then periodic Algorithm-1 planning.
-        self._reactive_moves()
-        if self._step_count % self.schedule_every == 0:
-            # Frontend lifecycle feeds the planner: per-request urgency
-            # (priority + deadline proximity) biases which debtor
-            # requests are offloaded first, so near-deadline requests
-            # get their memory relief before best-effort ones.
-            urgency = {rid: r.urgency(now)
-                       for rid, r in self.requests.items()
-                       if not r.done and (r.priority
-                                          or r.deadline_s is not None)}
-            for mv in self.gmanager.plan_moves(urgency=urgency):
-                self._execute_move(mv)
-
-        # Resume parked (preempted) requests before the decode sweep so
-        # a freed slot carries tokens this very step; the preemptor's
-        # guards keep it from stealing capacity the waiting queue (or a
-        # more urgent arrival) is entitled to.
-        if self.preemptor is not None:
-            self.preemptor.maybe_resume(now=now)
-
-        made = 0
-        for i, eng in self.engines.items():
-            if i in self._dead:
-                continue
-            made += eng.step()
+    def _drain(self) -> None:
+        """Finalize landed host-tier spills and release the creditor
+        spans of requests that finished since the last step."""
         if self.preemptor is not None:
             # Preempt-tier D2H spills finalize behind decode like the
             # shared tier's.
@@ -846,7 +877,6 @@ class Cluster:
                 if eng.rmanager.is_hosting(rid):
                     eng.drop_hosted(rid)
         self._pending_release.clear()
-        return made
 
     # ----------------------------------------------------------------- #
     def run_until_done(self, max_steps: int = 10_000) -> int:

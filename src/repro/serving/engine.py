@@ -52,7 +52,7 @@ from __future__ import annotations
 
 import functools
 import time
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
@@ -72,6 +72,7 @@ from repro.serving.kvpool import (build_local_tables, prefix_tables,
                                   write_pool_rows)
 from repro.serving.request import Request, RequestState
 from repro.serving.rmanager import RManager
+from repro.serving.tracing import Tracer
 
 
 @dataclass
@@ -79,8 +80,8 @@ class CommStats:
     """Bytes moved, per category — feeds the Fig. 4/11/12 benchmarks."""
     kv_moved: int = 0            # KV block migration (overlapped)
     query_shipped: int = 0       # q + (o, m, l) merge traffic per step
-    tokens_moved_steps: List[int] = field(default_factory=list)
-    host_gather_s: float = 0.0   # host-side table/step-input build time
+    moves: int = 0               # move legs executed out of this pool
+    host_gather_s: float = 0.0   # serve.build seconds (table/input build)
     decode_steps: int = 0
     # Decode steps whose jitted step COPIED the [L, NB, bs, K, hd] pool
     # instead of updating the donated buffer in place (0 on backends
@@ -170,7 +171,7 @@ class InstanceEngine:
                  max_local_len: int = 256, pool_blocks: int = 1024,
                  block_size: int = 16, inst_id: int = 0,
                  capacity_factor: float = -1.0, prefill_chunk: int = 32,
-                 gpool=None):
+                 gpool=None, tracer: Optional[Tracer] = None):
         self.params = params
         self.cfg = cfg
         self.inst_id = inst_id
@@ -190,6 +191,9 @@ class InstanceEngine:
         self.slots: List[Optional[Request]] = [None] * max_batch
         self.waiting: List[Request] = []
         self.stats = CommStats()
+        # Shared with the cluster: every engine's trace spans add to
+        # one set of totals.
+        self.tracer = tracer if tracer is not None else Tracer()
         self._key = jax.random.PRNGKey(1234 + inst_id)
         if gpool is not None and gpool.mesh is not None:
             from jax.sharding import NamedSharding
@@ -325,7 +329,17 @@ class InstanceEngine:
             self._finished_events.append(req.req_id)
             return True
         self.waiting.pop(0)
+        if req.admitted_at is None:
+            req.admitted_at = time.monotonic()
+        with self.tracer.span("serve.admit", inst=self.inst_id,
+                              req=req.req_id, tokens=T,
+                              chunks=-(-T // self.prefill_chunk)):
+            return self._admit_popped(req, slot, tokens, n_over, n_local)
 
+    def _admit_popped(self, req: Request, slot: int, tokens: List[int],
+                      n_over: int, n_local: int) -> bool:
+        """Prefill a request just taken off the waiting list into
+        ``slot`` and emit its first token; ``_admit_one``'s result."""
         if self._can_pool:
             logits = self._admit_streaming(req, tokens, n_over, n_local)
             if logits is None:                   # cluster-wide OOM
@@ -717,16 +731,19 @@ class InstanceEngine:
     def _sample_tokens(self, logits, reqs) -> np.ndarray:
         """Sampled tokens for a batch of slots: ONE device call + ONE
         host readback (not one per slot per step)."""
-        temps = jnp.asarray(
-            [(r.sampling.temperature if r is not None else 0.0)
-             for r in reqs], jnp.float32)
-        ks = [(r.sampling.top_k if r is not None else 0) for r in reqs]
-        if any(ks):
-            toks, self._key = _sample_batch_topk(
-                self._key, logits, temps, jnp.asarray(ks, jnp.int32))
-        else:
-            toks, self._key = _sample_batch(self._key, logits, temps)
-        return np.asarray(toks)
+        with self.tracer.span("serve.sample"):
+            temps = jnp.asarray(
+                [(r.sampling.temperature if r is not None else 0.0)
+                 for r in reqs], jnp.float32)
+            ks = [(r.sampling.top_k if r is not None else 0) for r in reqs]
+            if any(ks):
+                toks, self._key = _sample_batch_topk(
+                    self._key, logits, temps, jnp.asarray(ks, jnp.int32))
+            else:
+                toks, self._key = _sample_batch(self._key, logits, temps)
+        # The host waits here for the device to finish the step.
+        with self.tracer.span("serve.readback"):
+            return np.asarray(toks)
 
     def _emit(self, req: Request, tok: int) -> None:
         req.output.append(tok)
@@ -861,45 +878,47 @@ class InstanceEngine:
         if self.gpool is not None:
             return self._step_paged_global()
         pool = self.rmanager.pool
-        t0 = time.perf_counter()
-        self._append_step_tokens()
-        running = self.running
-        if not running:
-            return None
-        B, NB = self.max_batch, pool.alloc.num_blocks
-        tokens = np.zeros(B, np.int32)
-        lens = np.zeros(B, np.int32)
-        wblk = np.full(B, NB, np.int32)      # NB = out of range => dropped
-        woff = np.zeros(B, np.int32)
-        for i, r in enumerate(self.slots):
-            if r is None:
-                continue
-            tokens[i] = r.output[-1] if r.output else r.prompt[-1]
-            lens[i] = r.length - 1           # abs position of the new token
-            rb = pool.requests[r.req_id]
-            wblk[i] = rb.blocks[-1]
-            woff[i] = rb.tail_tokens - 1
-        insts = sorted({i for r in running
-                        for i in self.remote_insts.get(r.req_id, ())})
-        rank_pools = [pool] + [self.peers[i].rmanager.pool for i in insts]
-        req_ids = [r.req_id if r is not None else -1 for r in self.slots]
-        needed = max((len(p.requests[rid].blocks)
-                      for p in rank_pools for rid in req_ids
-                      if rid in p.requests), default=1)
-        tables, tails = build_local_tables(rank_pools, req_ids,
-                                           table_bucket(needed))
-        remote_pools = tuple((self.peers[i].pool_k, self.peers[i].pool_v)
-                             for i in insts)
-        self.stats.host_gather_s += time.perf_counter() - t0
+        with self.tracer.span("serve.build", inst=self.inst_id) as build:
+            self._append_step_tokens()
+            running = self.running
+            if not running:
+                return None
+            B, NB = self.max_batch, pool.alloc.num_blocks
+            tokens = np.zeros(B, np.int32)
+            lens = np.zeros(B, np.int32)
+            wblk = np.full(B, NB, np.int32)      # NB = out of range => dropped
+            woff = np.zeros(B, np.int32)
+            for i, r in enumerate(self.slots):
+                if r is None:
+                    continue
+                tokens[i] = r.output[-1] if r.output else r.prompt[-1]
+                lens[i] = r.length - 1       # abs position of the new token
+                rb = pool.requests[r.req_id]
+                wblk[i] = rb.blocks[-1]
+                woff[i] = rb.tail_tokens - 1
+            insts = sorted({i for r in running
+                            for i in self.remote_insts.get(r.req_id, ())})
+            rank_pools = [pool] + [self.peers[i].rmanager.pool for i in insts]
+            req_ids = [r.req_id if r is not None else -1 for r in self.slots]
+            needed = max((len(p.requests[rid].blocks)
+                          for p in rank_pools for rid in req_ids
+                          if rid in p.requests), default=1)
+            tables, tails = build_local_tables(rank_pools, req_ids,
+                                               table_bucket(needed))
+            remote_pools = tuple((self.peers[i].pool_k, self.peers[i].pool_v)
+                                 for i in insts)
+        self.stats.host_gather_s += build.seconds
         self.stats.decode_steps += 1
 
         # The pools are DONATED into the step: the returned arrays are
         # the same device buffers updated in place (stale-handle
         # discipline — self.pool_k/v are the only live references).
         ptr = buffer_ptr(self.pool_k)
-        logits, self.pool_k, self.pool_v = decode_step_paged(
-            self.params, self.cfg, tokens, lens, self.pool_k, self.pool_v,
-            tables, tails, wblk, woff, remote_pools=remote_pools)
+        with self.tracer.span("serve.decode"):
+            logits, self.pool_k, self.pool_v = decode_step_paged(
+                self.params, self.cfg, tokens, lens, self.pool_k,
+                self.pool_v, tables, tails, wblk, woff,
+                remote_pools=remote_pools)
         if ptr is not None and buffer_ptr(self.pool_k) != ptr:
             self.stats.pool_copy_steps += 1
 
@@ -925,47 +944,48 @@ class InstanceEngine:
         excluded from the tables (it enters as the self partial)."""
         gpool = self.gpool
         pool = self.rmanager.pool
-        t0 = time.perf_counter()
-        self._append_step_tokens()
-        running = self.running
-        if not running:
-            return None
-        B, NB = self.max_batch, pool.alloc.num_blocks
-        tokens = np.zeros(B, np.int32)
-        lens = np.zeros(B, np.int32)
-        wblk = np.full(B, NB, np.int32)      # NB = out of range => dropped
-        woff = np.zeros(B, np.int32)
-        req_ids = [r.req_id if r is not None else -1 for r in self.slots]
-        needed = max((len(p.requests[rid].blocks)
-                      for p in gpool.ranks for rid in req_ids
-                      if rid in p.requests), default=1)
-        tables, tails = build_local_tables(gpool.ranks, req_ids,
-                                           table_bucket(needed))
-        own = self.inst_id
-        for i, r in enumerate(self.slots):
-            if r is None:
-                continue
-            tokens[i] = r.output[-1] if r.output else r.prompt[-1]
-            lens[i] = r.length - 1       # abs position of the new token
-            rb = pool.requests[r.req_id]
-            wblk[i] = rb.blocks[-1]
-            woff[i] = rb.tail_tokens - 1
-            # Deferred-write schedule: the pending token's slot must not
-            # be visible to the pooled partial (its row is garbage until
-            # the post-scan scatter) — it joins as the self partial.
-            if rb.tail_tokens == 1:
-                tables[own, i, len(rb.blocks) - 1] = -1
-                tails[own, i] = self.block_size
-            else:
-                tails[own, i] = rb.tail_tokens - 1
-        self.stats.host_gather_s += time.perf_counter() - t0
+        with self.tracer.span("serve.build", inst=self.inst_id) as build:
+            self._append_step_tokens()
+            running = self.running
+            if not running:
+                return None
+            B, NB = self.max_batch, pool.alloc.num_blocks
+            tokens = np.zeros(B, np.int32)
+            lens = np.zeros(B, np.int32)
+            wblk = np.full(B, NB, np.int32)      # NB = out of range => dropped
+            woff = np.zeros(B, np.int32)
+            req_ids = [r.req_id if r is not None else -1 for r in self.slots]
+            needed = max((len(p.requests[rid].blocks)
+                          for p in gpool.ranks for rid in req_ids
+                          if rid in p.requests), default=1)
+            tables, tails = build_local_tables(gpool.ranks, req_ids,
+                                               table_bucket(needed))
+            own = self.inst_id
+            for i, r in enumerate(self.slots):
+                if r is None:
+                    continue
+                tokens[i] = r.output[-1] if r.output else r.prompt[-1]
+                lens[i] = r.length - 1       # abs position of the new token
+                rb = pool.requests[r.req_id]
+                wblk[i] = rb.blocks[-1]
+                woff[i] = rb.tail_tokens - 1
+                # Deferred-write schedule: the pending token's slot must not
+                # be visible to the pooled partial (its row is garbage until
+                # the post-scan scatter) — it joins as the self partial.
+                if rb.tail_tokens == 1:
+                    tables[own, i, len(rb.blocks) - 1] = -1
+                    tails[own, i] = self.block_size
+                else:
+                    tails[own, i] = rb.tail_tokens - 1
+        self.stats.host_gather_s += build.seconds
         self.stats.decode_steps += 1
 
         ptr = buffer_ptr(gpool.k)
-        logits, gpool.k, gpool.v = decode_step_global(
-            self.params, self.cfg, tokens, lens, gpool.k, gpool.v,
-            tables, tails, wblk, woff, rank=own, mesh=gpool.mesh,
-            pool_axes=gpool.pool_axes)
+        with self.tracer.span("serve.decode"):
+            logits, gpool.k, gpool.v = decode_step_global(
+                self.params, self.cfg, tokens, lens, gpool.k, gpool.v,
+                tables, tails, wblk, woff, rank=own, mesh=gpool.mesh,
+                pool_axes=gpool.pool_axes)
         if ptr is not None and buffer_ptr(gpool.k) != ptr:
             self.stats.pool_copy_steps += 1
 
@@ -1000,9 +1020,10 @@ class InstanceEngine:
             for i, r in enumerate(self.slots):
                 if r is not None:
                     tokens[i] = r.output[-1] if r.output else r.prompt[-1]
-            logits, self.state = decode_step(self.params, self.cfg,
-                                             self.state,
-                                             jnp.asarray(tokens))
+            with self.tracer.span("serve.decode"):
+                logits, self.state = decode_step(self.params, self.cfg,
+                                                 self.state,
+                                                 jnp.asarray(tokens))
             for r in self.running:
                 self.rmanager.pool.append_tokens(r.req_id, 1)
 

@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.serving.faults import (FrameCorruptionError, TransferError,
                                   backoff_delay_s)
+from repro.serving.tracing import Tracer
 
 
 @dataclass
@@ -51,7 +52,6 @@ class HostTierStats:
     spills: int = 0
     fetches: int = 0
     fetch_stalls: int = 0        # get() had to block on an in-flight D2H
-    stall_wait_s: float = 0.0    # host time spent blocked in stalls
     evictions: int = 0
     rejected: int = 0            # put() refused (tier full of pinned keys)
     fetch_retries: int = 0       # transient fetch errors absorbed by retry
@@ -81,7 +81,8 @@ class HostKVTier:
                  on_evict: Optional[Callable[[Any], None]] = None,
                  evictable_fn: Optional[Callable[[Any], bool]] = None,
                  verify: bool = False, max_retries: int = 0,
-                 backoff_base_s: float = 0.0, backoff_max_s: float = 0.05):
+                 backoff_base_s: float = 0.0, backoff_max_s: float = 0.05,
+                 tracer: Optional[Tracer] = None):
         assert capacity_blocks >= 0
         assert 0.0 < low_watermark <= high_watermark <= 1.0
         self.capacity = capacity_blocks
@@ -106,6 +107,7 @@ class HostKVTier:
         # "error" (inject a transient TransferError) or "corrupt"
         # (bit-flip the stored frame). See serving.faults.
         self.fault_hook: Optional[Callable[[Any], Optional[str]]] = None
+        self.tracer = tracer if tracer is not None else Tracer()
 
     # ----------------------------------------------------------------- #
     @property
@@ -231,12 +233,12 @@ class HostKVTier:
     def _get_once(self, key: Any) -> Optional[Tuple[np.ndarray, np.ndarray]]:
         if key in self._pending:
             k, v = self._pending.pop(key)
-            stalled = not (self._is_ready(k) and self._is_ready(v))
-            t0 = time.perf_counter()
-            self._finalize(key, k, v)
-            if stalled:
+            if self._is_ready(k) and self._is_ready(v):
+                self._finalize(key, k, v)
+            else:
+                with self.tracer.span("serve.sync", tag="host_fetch"):
+                    self._finalize(key, k, v)
                 self.stats.fetch_stalls += 1
-                self.stats.stall_wait_s += time.perf_counter() - t0
         if key in self._frames and self.fault_hook is not None:
             mode = self.fault_hook(key)
             if mode == "error":

@@ -100,7 +100,8 @@ class Preemptor:
                                verify=fpol.verify_host_frames,
                                max_retries=fpol.max_transfer_retries,
                                backoff_base_s=fpol.retry_backoff_base_s,
-                               backoff_max_s=fpol.retry_backoff_max_s)
+                               backoff_max_s=fpol.retry_backoff_max_s,
+                               tracer=cluster.tracer)
         self.paused: Dict[int, _PausedRecord] = {}
         self.stats = PreemptStats()
         # Best urgency among the frontend's still-queued requests (set

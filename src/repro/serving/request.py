@@ -92,6 +92,9 @@ class Request:
     output: List[int] = field(default_factory=list)
     # --- lifecycle timestamps (time.monotonic domain) ------------------ #
     arrival_time: float = 0.0         # set at server/cluster submit
+    # When an engine first started admitting it (queue wait ends); a
+    # pause, resume or replay never moves it.
+    admitted_at: Optional[float] = None
     finish_time: Optional[float] = None
     token_times: List[float] = field(default_factory=list)  # per emit
     # --- frontend scheduling ------------------------------------------- #

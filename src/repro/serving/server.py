@@ -159,6 +159,7 @@ class LLMServer:
         self.config = config if config is not None else ServingConfig()
         self.cluster = Cluster(params, cfg, self.config, perf=perf,
                                mesh=mesh, layout=layout)
+        self.tracer = self.cluster.tracer
         self._ids = RequestIdAllocator()
         self._handles: Dict[int, RequestHandle] = {}
         self._queue: List[Request] = []      # admitted, not yet dispatched
@@ -282,10 +283,12 @@ class LLMServer:
     def step(self, now: Optional[float] = None) -> int:
         """One frontend iteration: dispatch, overload control (paused
         victims / preempted slots when enabled), then one cluster step."""
-        now = time.monotonic() if now is None else now
-        self._dispatch(now)
-        self._overload_control(now)
-        return self.cluster.step(now=now)
+        with self.tracer.span("serve.step"):
+            now = time.monotonic() if now is None else now
+            with self.tracer.span("serve.dispatch"):
+                self._dispatch(now)
+                self._overload_control(now)
+            return self.cluster.step(now=now)
 
     def drain(self, max_steps: int = 10_000) -> int:
         """Drive until every submitted request is terminal (closed-loop
@@ -323,8 +326,11 @@ class LLMServer:
         live requests), host-tier occupancy, and cumulative spill /
         prefetch / hit traffic — plus fault-tolerance counters (dead
         ranks, token-replay recoveries, replayed tokens, transfer
-        retries/failures, frame corruptions). Cache, host-tier, and
-        fault entries are present (as zeros) even when the features are
+        retries/failures, frame corruptions) — and the serving loop's
+        trace-span totals, ``trace.<name>.s`` (seconds) and
+        ``trace.<name>.n`` (count) for every name in
+        ``repro.serving.tracing.NAMES``. Cache, host-tier, fault and
+        trace entries are present (as zeros) even when the features are
         off/quiet, so dashboards keyed on the names never miss."""
         cl = self.cluster
         total = used = free = 0
@@ -401,6 +407,7 @@ class LLMServer:
             "transfer_failures": failures,
             "host_frame_corruptions": corruptions,
         })
+        out.update(self.tracer.totals())
         return out
 
     # --- open-loop event pump ------------------------------------------ #

@@ -35,6 +35,7 @@ from typing import Any, Callable, Deque, Dict, Optional, Tuple
 import jax
 
 from repro.serving.faults import TransferError, backoff_delay_s
+from repro.serving.tracing import Tracer
 
 
 class AsyncStager:
@@ -58,7 +59,8 @@ class AsyncStager:
 
     def __init__(self, overlap: bool = True, depth: int = 2, *,
                  max_retries: int = 0, backoff_base_s: float = 0.0,
-                 backoff_max_s: float = 0.05):
+                 backoff_max_s: float = 0.05,
+                 tracer: Optional[Tracer] = None):
         self.overlap = overlap
         self.depth = max(1, depth)
         self.max_retries = max(0, max_retries)
@@ -67,14 +69,14 @@ class AsyncStager:
         self._inflight: Deque[Tuple[Any, Optional[str]]] = deque()
         self.staged = 0          # copy chains handed to the stager
         self.synced = 0          # explicit block_until_ready calls
-        self.sync_wait_s = 0.0   # host time spent blocked on copies
+        self.sync_wait_s = 0.0   # serve.sync seconds: blocked on copies
         self.stalls: Dict[str, int] = defaultdict(int)
-        self.stall_wait_s: Dict[str, float] = defaultdict(float)
         self.retries: Dict[str, int] = defaultdict(int)
         self.failures: Dict[str, int] = defaultdict(int)
         # Chaos hook: called with the chain's tag before each wait; a
         # True return injects one TransferError (see serving.faults).
         self.fault_hook: Optional[Callable[[Optional[str]], bool]] = None
+        self.tracer = tracer if tracer is not None else Tracer()
 
     def stage(self, arrays: Any, tag: Optional[str] = None) -> None:
         """Register one dispatched copy chain (any pytree of arrays).
@@ -146,11 +148,9 @@ class AsyncStager:
                 if not (hasattr(x, "is_deleted") and x.is_deleted())]
         stalled = any(not x.is_ready() for x in live
                       if hasattr(x, "is_ready"))
-        t0 = time.perf_counter()
-        jax.block_until_ready(live)
-        waited = time.perf_counter() - t0
-        self.sync_wait_s += waited
+        with self.tracer.span("serve.sync", tag=tag or "untagged") as sync:
+            jax.block_until_ready(live)
+        self.sync_wait_s += sync.seconds
         self.synced += 1
         if stalled and tag is not None:
             self.stalls[tag] += 1
-            self.stall_wait_s[tag] += waited

@@ -170,11 +170,9 @@ def test_move_is_metadata_only(setup):
     owner = creditor = None
     moved = False
     for _ in range(200):
-        pre_moves = sum(len(e.stats.tokens_moved_steps)
-                        for e in cl.engines.values())
+        pre_moves = sum(e.stats.moves for e in cl.engines.values())
         cl.step()
-        post_moves = sum(len(e.stats.tokens_moved_steps)
-                         for e in cl.engines.values())
+        post_moves = sum(e.stats.moves for e in cl.engines.values())
         if not moved and post_moves > pre_moves:
             moved = True
             owner = next(e for e in cl.engines.values()
@@ -213,8 +211,7 @@ def test_recompile_count_bounded_by_buckets(setup):
     traces = prefill_mod.paged_trace_count() - before
 
     assert req.state == RequestState.FINISHED
-    n_moves = sum(len(e.stats.tokens_moved_steps)
-                  for e in cl.engines.values())
+    n_moves = sum(e.stats.moves for e in cl.engines.values())
     assert n_moves >= 4, f"wanted >=4 KV moves, got {n_moves}"
     assert 1 <= traces <= 2, \
         f"decode step retraced {traces}x across {n_moves} moves"
