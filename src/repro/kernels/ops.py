@@ -164,36 +164,66 @@ def paged_prefill_attention(q, pool_k, pool_v, table, tail_len, *,
     return o, m, l
 
 
+# Bytes of one K or V tile buffer of the paged decode kernel (four are
+# live: K and V, double-buffered): 16 blocks of mistral-nemo-12b's
+# (16, 8, 128) bf16 or 8 of olmo-1b's (16, 16, 128). On a v5e these tiles
+# ran the kernel at 83-85% (nemo) and 77% (olmo) of its HBM roofline;
+# twice and four times as large ran no faster, and slower for olmo-1b's
+# short rows, whose last tile is mostly padding slots that are computed.
+_DECODE_TILE_BYTES = 512 * 1024
+
+
+def _decode_tile_blocks(block_bytes: int, max_blocks: int) -> int:
+    """Table slots per compute tile of the paged decode kernel: the
+    largest power of two <= ``max_blocks`` whose blocks fit one tile
+    buffer (at least one)."""
+    pb = 1
+    while 2 * pb <= max_blocks and 2 * pb * block_bytes <= _DECODE_TILE_BYTES:
+        pb *= 2
+    return pb
+
+
+def _paged_decode_pallas(q, pools_k, pools_v, tables, tails, scale,
+                         interpret):
+    """The decode kernel over stacked rank pools; see
+    ``paged_micro_attention_ranks``."""
+    D = q.shape[-1]
+    kp = _pad_last(pools_k, 128)
+    vp = _pad_last(pools_v, 128)
+    NR, NB, bs, K, Dp = kp.shape
+    pb = _decode_tile_blocks(bs * K * Dp * kp.dtype.itemsize,
+                             tables.shape[-1])
+    nblk = jnp.sum(tables >= 0, axis=-1).astype(jnp.int32)
+    o, m, l = paged_micro_attention_kernel(
+        _pad_last(q, 128), kp, vp, tables, nblk, tails, scale=scale,
+        tile_blocks=pb, interpret=_interpret(interpret))
+    return o[..., :D], m[:, :, 0], l[:, :, 0]
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "backend"))
 def paged_micro_attention(q, pool_k, pool_v, table, tail_len, *,
                           scale=None, interpret=None, backend=None):
     """Paged DistAttention MicroAttention partial (decode).
 
-    q [R,H,D]; pool_k/v [NB,bs,K,D]; table [R,MB] (-1 padded, seq order);
-    tail_len [R] valid tokens in each request's LAST local slot.
+    q [R,H,D]; pool_k/v [NB,bs,K,D]; table [R,MB] (valid prefix in
+    sequence order, -1 padded); tail_len [R] valid tokens in each
+    request's LAST local slot.
     ``backend``: "pallas" (kernel; interpret mode off-TPU) or "jnp" (pure
     gather fallback); None picks pallas on TPU and jnp elsewhere.
     Returns (o [R,H,D] f32 unnormalized, m [R,H] f32, l [R,H] f32).
     """
-    R, H, D = q.shape
     if scale is None:
-        scale = D ** -0.5
+        scale = q.shape[-1] ** -0.5
+    table = table.astype(jnp.int32)
+    tail_len = tail_len.astype(jnp.int32)
     if backend is None:
         backend = "pallas" if (_on_tpu() or interpret is not None) else "jnp"
     if backend == "jnp":
-        return paged_micro_attention_jnp(q, pool_k, pool_v,
-                                         table.astype(jnp.int32),
-                                         tail_len.astype(jnp.int32),
+        return paged_micro_attention_jnp(q, pool_k, pool_v, table, tail_len,
                                          scale=scale)
-    nblk = jnp.sum(table >= 0, axis=1).astype(jnp.int32)
-    qp = _pad_last(q, 128)
-    kp = _pad_last(pool_k, 128)
-    vp = _pad_last(pool_v, 128)
-    o, m, l = paged_micro_attention_kernel(
-        qp, kp, vp, table.astype(jnp.int32), nblk,
-        tail_len.astype(jnp.int32), scale=scale,
-        interpret=_interpret(interpret))
-    return o[:, :, :D], m[:, 0], l[:, 0]
+    o, m, l = _paged_decode_pallas(q, pool_k[None], pool_v[None], table[None],
+                                   tail_len[None], scale, interpret)
+    return o[0], m[0], l[0]
 
 
 def paged_micro_attention_ranks(q, pools_k, pools_v, tables, tails, *,
@@ -201,15 +231,23 @@ def paged_micro_attention_ranks(q, pools_k, pools_v, tables, tails, *,
     """Decode MicroAttention partials over a stacked set of rank pools.
 
     q [R,H,D] broadcast to every rank; pools_k/v [NR,NB,bs,K,D] one pool
-    slab per rank; tables [NR,R,MB]; tails [NR,R]. Returns stacked
-    partials (o [NR,R,H,D], m [NR,R,H], l [NR,R,H]) — merge with
-    ``merge_partials(axis=0)`` (vmap path) or compute per-shard inside
-    shard_map and merge with ``merge_partials_collective``.
+    slab per rank; tables [NR,R,MB]; tails [NR,R]. One kernel call
+    covers every rank. Returns stacked partials (o [NR,R,H,D],
+    m [NR,R,H], l [NR,R,H]) — merge with ``merge_partials(axis=0)`` or
+    compute per-shard inside shard_map and merge with
+    ``merge_partials_collective``.
     """
-    return jax.vmap(
-        lambda pk, pv, tb, tl: paged_micro_attention(
-            q, pk, pv, tb, tl, scale=scale, backend=backend)
-    )(pools_k, pools_v, tables, tails)
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    tables = tables.astype(jnp.int32)
+    tails = tails.astype(jnp.int32)
+    if resolve_backend(backend) == "jnp":
+        return jax.vmap(
+            lambda pk, pv, tb, tl: paged_micro_attention_jnp(
+                q, pk, pv, tb, tl, scale=scale)
+        )(pools_k, pools_v, tables, tails)
+    return _paged_decode_pallas(q, pools_k, pools_v, tables, tails, scale,
+                                None)
 
 
 def paged_prefill_attention_ranks(q, pools_k, pools_v, tables, tails, *,

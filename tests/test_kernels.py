@@ -48,13 +48,17 @@ def test_flash_prefill_sliding_window(window):
                                atol=2e-5, rtol=2e-5)
 
 
-def _make_pool(key, R, NB, bs, K, D, MB, dtype, rng):
-    """Random pool + tables with variable block counts and tail lengths."""
+def _make_pool(key, R, NB, bs, K, D, MB, dtype, rng, rows=None):
+    """Random pool + tables with variable block counts and tail lengths;
+    ``rows`` fixes each row's (block count, tail length) instead."""
     kk, kv = jax.random.split(key)
     pool_k = _rand(kk, (NB, bs, K, D), dtype)
     pool_v = _rand(kv, (NB, bs, K, D), dtype)
     table = -np.ones((R, MB), np.int32)
-    nblk = rng.integers(0, MB + 1, size=R)
+    if rows is None:
+        nblk = rng.integers(0, MB + 1, size=R)
+    else:
+        nblk = np.asarray([n for n, _ in rows])
     tail = np.ones((R,), np.int32)
     perm = rng.permutation(NB)
     used = 0
@@ -66,25 +70,40 @@ def _make_pool(key, R, NB, bs, K, D, MB, dtype, rng):
             nblk[r] = n
         table[r, :n] = take
         used += n
-        tail[r] = rng.integers(1, bs + 1) if n else bs
+        if rows is not None:
+            tail[r] = rows[r][1]
+        else:
+            tail[r] = rng.integers(1, bs + 1) if n else bs
     return pool_k, pool_v, jnp.asarray(table), jnp.asarray(nblk, jnp.int32), \
         jnp.asarray(tail)
 
 
-@pytest.mark.parametrize("R,NB,bs,K,G,D,MB", [
-    (4, 16, 16, 2, 2, 16, 4),
-    (3, 32, 8, 1, 4, 32, 8),      # MQA
-    (2, 8, 32, 4, 1, 112, 3),     # MHA, unaligned head dim
+@pytest.mark.parametrize("R,NB,bs,K,G,D,MB,rows", [
+    pytest.param(4, 16, 16, 2, 2, 16, 4, None, id="4-16-16-2-2-16-4"),
+    pytest.param(3, 32, 8, 1, 4, 32, 8, None,          # MQA
+                 id="3-32-8-1-4-32-8"),
+    pytest.param(2, 8, 32, 4, 1, 112, 3, None,         # MHA, unaligned D
+                 id="2-8-32-4-1-112-3"),
+    # Fixed (nblk, tail) rows over several compute tiles. The kernel's
+    # tile (pb slots) comes from the block's bytes: 16 (bf16) / 8 (f32)
+    # for nemo-like GQA blocks, 8 / 4 for olmo-like MHA blocks. Rows span
+    # several tiles, end mid-tile or on a tile's edge, have a tail of 1
+    # or of bs, or are empty next to full rows; MB is no multiple of pb.
+    pytest.param(4, 96, 16, 8, 4, 128, 40,
+                 ((40, 16), (0, 16), (17, 1), (16, 16)), id="gqa-tiles"),
+    pytest.param(4, 48, 16, 16, 1, 128, 20,
+                 ((0, 16), (20, 1), (9, 16), (1, 1)), id="mha-tiles"),
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_micro_attention_matches_ref(R, NB, bs, K, G, D, MB, dtype):
+def test_paged_micro_attention_matches_ref(R, NB, bs, K, G, D, MB, rows,
+                                           dtype):
     H = K * G
     rng = np.random.default_rng(0)
     key = jax.random.PRNGKey(3)
     kq, kp = jax.random.split(key)
     q = _rand(kq, (R, H, D), dtype)
     pool_k, pool_v, table, nblk, tail = _make_pool(kp, R, NB, bs, K, D, MB,
-                                                   dtype, rng)
+                                                   dtype, rng, rows)
     got_o, got_m, got_l = paged_micro_attention(q, pool_k, pool_v, table,
                                                 tail, interpret=True)
     want_o, want_m, want_l = ref.paged_micro_attention_ref(
@@ -96,6 +115,21 @@ def test_paged_micro_attention_matches_ref(R, NB, bs, K, G, D, MB, dtype):
                                atol=tol, rtol=tol)
     np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
                                atol=tol, rtol=tol)
+    empty = np.asarray(nblk) == 0           # the merge identity, exactly
+    assert (np.asarray(got_o)[empty] == 0).all()
+    assert np.isneginf(np.asarray(got_m)[empty]).all()
+    assert (np.asarray(got_l)[empty] == 0).all()
+
+
+@pytest.mark.parametrize("block,max_blocks,want", [
+    (16 * 8 * 128 * 2, 1024, 16),     # mistral-nemo-12b bf16 blocks
+    (16 * 16 * 128 * 2, 128, 8),      # olmo-1b bf16 blocks
+    (16 * 16 * 128 * 2, 3, 2),        # capped by a narrow table
+    (4 << 20, 64, 1),                 # a block larger than a tile
+])
+def test_decode_tile_blocks_from_shapes(block, max_blocks, want):
+    from repro.kernels.ops import _decode_tile_blocks
+    assert _decode_tile_blocks(block, max_blocks) == want
 
 
 def test_paged_partial_merges_to_full_attention():

@@ -145,6 +145,54 @@ def test_decode_step_paged_matches_dist(setup):
 
 
 # ------------------------------------------------------------------ #
+# Global-pool decode == per-instance paged decode, both on the kernel
+# ------------------------------------------------------------------ #
+def test_global_decode_kernel_matches_paged_decode(setup):
+    """One kernel call over the stacked rank pools (global pool) gives
+    the logits of one call per pool (per-instance paged decode), with
+    the Pallas kernel in interpret mode on both paths."""
+    from repro.serving.sharded_step import decode_step_global
+    cfg, params = setup
+    B, T, bs, NB, n_over = 2, 40, 8, 16, 16
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (B, T + 1), 0,
+                                cfg.vocab_size)
+    _, full = prefill(params, cfg, tokens[:, :T], max_len=T + 8)
+    L, K, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+    pools = [RankKVPool(NB, bs), RankKVPool(NB, bs)]    # owner, creditor
+    kv = [[jnp.zeros((L, NB, bs, K, hd), jnp.dtype(cfg.dtype))
+           for _ in range(2)] for _ in pools]
+    for b in range(B):
+        for p, (t0, t1) in enumerate(((n_over, T), (0, n_over))):
+            pools[p].append_tokens(b, t1 - t0)
+            blocks = pools[p].requests[b].blocks
+            for i, cache in enumerate((full.kv_k, full.kv_v)):
+                kv[p][i] = write_pool_rows(kv[p][i], blocks,
+                                           cache[:, b, t0:t1], bs)
+    # The global step takes tables without the pending token (it joins
+    # as a self partial); the paged step writes it first and attends it.
+    g_tables, g_tails = build_local_tables(pools, list(range(B)), 8)
+    wblk, woff = np.zeros(B, np.int32), np.zeros(B, np.int32)
+    for b in range(B):
+        pools[0].append_tokens(b, 1)
+        wblk[b] = pools[0].requests[b].blocks[-1]
+        woff[b] = pools[0].requests[b].tail_tokens - 1
+    p_tables, p_tails = build_local_tables(pools, list(range(B)), 8)
+    lens = np.full(B, T, np.int32)
+    gk = jnp.stack([kv[0][0], kv[1][0]])
+    gv = jnp.stack([kv[0][1], kv[1][1]])
+    lg_global, _, _ = decode_step_global(
+        params, cfg, tokens[:, T], lens, gk, gv, g_tables, g_tails, wblk,
+        woff, rank=0, backend="pallas")
+    lg_paged, _, _ = decode_step_paged(
+        params, cfg, tokens[:, T], lens, kv[0][0], kv[0][1], p_tables,
+        p_tails, wblk, woff, remote_pools=((kv[1][0], kv[1][1]),),
+        backend="pallas")
+    np.testing.assert_allclose(np.asarray(lg_global, np.float32),
+                               np.asarray(lg_paged, np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
+# ------------------------------------------------------------------ #
 # A KV move is metadata + pool rows only; logits survive the boundary
 # ------------------------------------------------------------------ #
 def test_move_is_metadata_only(setup):

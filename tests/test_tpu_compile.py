@@ -44,28 +44,36 @@ def _assert_kernel(lowered):
     assert "tpu_custom_call" in text, "the Pallas kernel is not in the program"
 
 
-@pytest.mark.parametrize("H,K,hd,ranks", [
-    (16, 16, 128, 0),     # olmo-1b (MHA)
-    (32, 8, 128, 0),      # mistral-nemo-12b (GQA)
-    (64, 8, 112, 0),      # kimi-k2 config's head dim, padded to 128
-    (16, 16, 128, 4),     # olmo-1b over 4 stacked rank pools (vmap)
+@pytest.mark.parametrize("H,K,hd,ranks,R,nb,mb", [
+    pytest.param(16, 16, 128, 0, 16, NB, MB,         # olmo-1b (MHA)
+                 id="16-16-128-0"),
+    pytest.param(32, 8, 128, 0, 16, NB, MB,          # mistral-nemo (GQA)
+                 id="32-8-128-0"),
+    pytest.param(64, 8, 112, 0, 16, NB, MB,          # kimi-k2 head dim
+                 id="64-8-112-0"),
+    pytest.param(16, 16, 128, 4, 16, NB, MB,         # 4 stacked rank pools
+                 id="16-16-128-4"),
+    # The benchmark cells' decode calls: nemo12b-longdecode's instance
+    # pool and table bucket, olmo1b-chat's batch, pool and bucket.
+    pytest.param(32, 8, 128, 0, 16, 3072, 1024, id="nemo12b-longdecode"),
+    pytest.param(16, 16, 128, 0, 32, 2048, 128, id="olmo1b-chat"),
 ])
-def test_paged_decode_compiles(one_chip, monkeypatch, H, K, hd, ranks):
-    R = 16
+def test_paged_decode_compiles(one_chip, monkeypatch, H, K, hd, ranks, R,
+                               nb, mb):
     q = _spec(one_chip, (R, H, hd))
     if not ranks:
-        pool = _spec(one_chip, (NB, BS, K, hd))
+        pool = _spec(one_chip, (nb, BS, K, hd))
         lowered = ops.paged_micro_attention.lower(
-            q, pool, pool, _spec(one_chip, (R, MB), jnp.int32),
+            q, pool, pool, _spec(one_chip, (R, mb), jnp.int32),
             _spec(one_chip, (R,), jnp.int32), backend="pallas",
             interpret=False)
     else:
         # The global-pool path resolves interpret from the process's
         # backend; this process is on the CPU, the program is for a TPU.
         monkeypatch.setattr(ops, "_on_tpu", lambda: True)
-        pools = _spec(one_chip, (ranks, NB // ranks, BS, K, hd))
+        pools = _spec(one_chip, (ranks, nb // ranks, BS, K, hd))
         lowered = jax.jit(ops.paged_micro_attention_ranks).lower(
-            q, pools, pools, _spec(one_chip, (ranks, R, MB), jnp.int32),
+            q, pools, pools, _spec(one_chip, (ranks, R, mb), jnp.int32),
             _spec(one_chip, (ranks, R), jnp.int32))
     _assert_kernel(lowered)
 
