@@ -39,10 +39,12 @@ class Record:
     output: List[int] = field(default_factory=list)
     state: str = ""
     finish_time: Optional[float] = None
+    admitted_at: Optional[float] = None     # admission began (monotonic)
 
     def freeze(self) -> None:
         """Copy the request's tokens, times and state; drop the handle."""
         req = self.handle._req
+        self.admitted_at = getattr(req, "admitted_at", None)
         self.token_times = list(req.token_times)
         self.output = list(req.output)
         self.state = req.state.name

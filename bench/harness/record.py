@@ -2,20 +2,24 @@
 
 Every metric is a file ``metrics/<name>.py`` with one function
 ``read(run: RunRecord) -> float | None``; ``None`` means the run had
-nothing to read and the metric is left out of the result line.
+nothing to read and the metric is left out of the result line. Besides
+the requests, the step samples and the trace, a reader sees every
+numeric entry of the program's ``server.metrics`` (counters, and the
+serving loop's trace-span totals ``trace.<name>.s`` / ``.n``) as read
+just before and just after the window (``RunRecord.program_delta``):
+a counter that the program adds reaches a new reader with no edit here.
 """
 from __future__ import annotations
 
-import importlib.util
 import os
 from dataclasses import dataclass, field
+from types import ModuleType
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from harness import counts
 from harness.pump import Record, StepSample
-from harness.spec import BENCH_DIR, ModelSpec
+from harness.spec import BENCH_DIR, load_file
 from harness.trace import Trace, to_trace_clock, union
 
 DECODE_STEP = "_decode_step_paged_jit"
@@ -26,8 +30,10 @@ PREFILL_KERNEL = "paged_prefill_attention"
 
 @dataclass
 class RunRecord:
-    """One run: the cell, the window, the requests and the trace."""
-    model: ModelSpec
+    """One run: the cell, the window, the requests and the trace;
+    ``arch`` is the cell's architecture module and ``model`` its spec."""
+    arch: ModuleType
+    model: Any
     serving: Dict[str, Any]
     chips: int
     window: Tuple[float, float]          # monotonic (start, end)
@@ -35,6 +41,7 @@ class RunRecord:
     records: List[Record]
     samples: List[StepSample] = field(default_factory=list)
     engine_delta: Dict[str, float] = field(default_factory=dict)
+    program: Dict[str, Dict[str, float]] = field(default_factory=dict)
     memory: Dict[str, int] = field(default_factory=dict)
     peaks: Dict[str, Any] = field(default_factory=dict)
     trace: Optional[Trace] = None
@@ -79,6 +86,15 @@ class RunRecord:
                 out.append(w1 - r.due)
         return out
 
+    def program_delta(self, key: str) -> Optional[float]:
+        """How much the program's ``server.metrics`` entry ``key`` grew
+        over the window (``program["before"]`` to ``["after"]``); None
+        where the program does not report it."""
+        before, after = (self.program.get(k, {}) for k in ("before", "after"))
+        if key not in before or key not in after:
+            return None
+        return after[key] - before[key]
+
     # --- work counted from shapes -------------------------------------- #
     def window_work(self) -> Dict[str, float]:
         """Model operations of the window's tokens, and the decode and
@@ -88,7 +104,7 @@ class RunRecord:
         it counts in the window when that token does; output token i
         >= 1 comes from a decode step over ``prompt + i`` tokens. A
         context is held in as many pools as its local quotas need."""
-        m, bs = self.model, self.serving["block_size"]
+        a, m, bs = self.arch, self.model, self.serving["block_size"]
         C = self.serving["prefill_chunk"]
         cap = self.serving["max_local_len"] - bs
         out = dict(model_flops=0.0, decode_flops=0.0, decode_bytes=0.0,
@@ -97,20 +113,20 @@ class RunRecord:
         for r, i, _t in self.window_tokens():
             T = len(r.prompt)
             if i == 0:
-                out["model_flops"] += counts.prompt_flops(m, T)
+                out["model_flops"] += a.prompt_flops(m, T)
                 out["prompt_tokens"] += T
                 spans = -(-T // cap)
                 for t0 in range(0, T, C):
-                    f, b = counts.prefill_attn_work(
+                    f, b = a.prefill_attn_work(
                         m, min(C, T - t0), t0, spans)
                     out["prefill_flops"] += f
                     out["prefill_bytes"] += b
             else:
                 ctx = T + i
-                out["model_flops"] += counts.token_flops(m, ctx, True)
+                out["model_flops"] += a.token_flops(m, ctx, True)
                 out["decode_tokens"] += 1
                 spans = -(-ctx // cap)
-                f, b = counts.decode_attn_work(m, ctx, spans)
+                f, b = a.decode_attn_work(m, ctx, spans)
                 out["decode_flops"] += f
                 out["decode_bytes"] += b
         return out
@@ -151,9 +167,5 @@ def percentile(xs: List[float], q: float) -> Optional[float]:
 
 def load_reader(name: str) -> Callable[[RunRecord], Optional[float]]:
     """The ``read`` function of ``metrics/<name>.py``."""
-    path = os.path.join(BENCH_DIR, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_file(os.path.join(BENCH_DIR, "metrics", f"{name}.py"),
+                     f"bench_metric_{name.replace('.', '_')}").read

@@ -81,18 +81,11 @@ class CompileWatch:
 
 
 def program_config(cell: Cell):
-    """The program's ModelConfig and ServingConfig for a cell."""
-    from repro.configs.base import ModelConfig
+    """The program's ModelConfig (from the cell's architecture) and
+    ServingConfig for a cell."""
     from repro.serving import ServingConfig
-    m = cell.model
-    cfg = ModelConfig(
-        name=m.name, family="dense", num_layers=m.layers, d_model=m.d_model,
-        num_heads=m.heads, num_kv_heads=m.kv_heads, head_dim=m.head_dim,
-        d_ff=m.d_ff, vocab_size=m.vocab,
-        norm_type="rmsnorm" if m.norm == "rmsnorm" else "nonparametric_ln",
-        activation="swiglu", rope_theta=m.rope_theta, tie_embeddings=m.tied,
-        dtype=m.dtype)
-    return cfg, serving_config(ServingConfig, cell.serving)
+    return (cell.arch.program_config(cell.model),
+            serving_config(ServingConfig, cell.serving))
 
 
 def serving_config(cls, kw: Dict[str, Any]):
@@ -124,6 +117,14 @@ def engine_totals(server) -> Dict[str, float]:
     engs = server.cluster.engines.values()
     return {"host_gather_s": sum(e.stats.host_gather_s for e in engs),
             "decode_steps": sum(e.stats.decode_steps for e in engs)}
+
+
+def program_counters(server) -> Dict[str, float]:
+    """The numeric entries of the program's ``server.metrics``: its
+    counters and trace-span totals (none from a program without it)."""
+    counters = getattr(server, "metrics", None) or {}
+    return {k: float(v) for k, v in counters.items()
+            if isinstance(v, (int, float))}
 
 
 def trace_counts() -> Tuple[int, int]:
@@ -252,7 +253,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     from repro.launch.compile_cache import enable_compile_cache
     from repro.serving import LLMServer
 
-    from harness.weights import program_params, root_key
+    from harness.weights import root_key
 
     cache_dir = "off"
     if require_tpu:
@@ -270,7 +271,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
 
     cfg, sc = program_config(cell)
     mesh, layout = program_mesh(cell, devices)
-    params = program_params(root_key(seed), m)
+    params = cell.arch.program_params(root_key(seed), m)
     jax.block_until_ready(params)
     server = LLMServer(params, cfg, sc, mesh=mesh, layout=layout)
     pump = Pump(server, sample_steps=trace)
@@ -285,7 +286,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
-    before = (engine_totals(server), trace_counts())
+    before = (engine_totals(server), trace_counts(),
+              program_counters(server))
     pump.stall_watch = stalls = StallWatch()
     stalls.start()
     w0 = time.monotonic()
@@ -295,7 +297,8 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     w1 = time.monotonic()
     watch.in_window = False
     stalls.stop()
-    after = (engine_totals(server), trace_counts())
+    after = (engine_totals(server), trace_counts(),
+             program_counters(server))
     if trace:
         jax.profiler.stop_trace()
     mem = [d.memory_stats() or {} for d in devices]
@@ -319,9 +322,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         shutil.rmtree(trace_dir, ignore_errors=True)
 
     run = RunRecord(
-        model=m, serving=cell.serving, chips=cell.chips, window=(w0, w1),
-        setup_s=w0 - t_start, records=records, samples=samples,
+        arch=cell.arch, model=m, serving=cell.serving, chips=cell.chips,
+        window=(w0, w1), setup_s=w0 - t_start, records=records,
+        samples=samples,
         engine_delta={k: after[0][k] - before[0][k] for k in before[0]},
+        program={"before": before[2], "after": after[2]},
         memory={"peak_bytes_in_use": peak, "bytes_limit": limit},
         peaks=pk, trace=tr)
     metrics = {}
@@ -378,7 +383,7 @@ def judge(cell: Cell, seed: int, records: List[Record], window,
     sample = pick_sample(records, window, check, seed)
     seqs = [(r.prompt, r.output) for r in sample]
     t = time.monotonic()
-    g = reference.gaps(cell.model, seed, seqs, check["pad_to"],
+    g = cell.arch.gaps(cell.model, seed, seqs, check["pad_to"],
                        control=control) if seqs else {}
     served = g.get("served")
     n_tok = 0 if served is None else int(served.size)

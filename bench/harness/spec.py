@@ -2,19 +2,27 @@
 
 A cell (``workloads`` entry) names a configuration and a traffic mix. The
 configuration's file holds the model's published sizes (Hugging Face key
-names), the serving deployment and the correctness limit; the traffic
-mix is ``traffic/<name>.json``; each metric is ``metrics/<name>.py``.
-Nothing here imports JAX or the program.
+names), the architecture that reads them (``"arch"``, ``archs/<arch>.py``;
+``"dense"`` when absent), the serving deployment and the correctness
+limit; the traffic mix is ``traffic/<name>.json``; each metric is
+``metrics/<name>.py``. Nothing here imports JAX or the program: an
+architecture's module, which does, is loaded when a cell first asks for
+its model.
 """
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 import os
+import sys
 from dataclasses import dataclass
+from types import ModuleType
 from typing import Any, Dict, List, Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
+ARCH_DIR = os.path.join(BENCH_DIR, "archs")
 
 
 def load_json(path: str) -> Any:
@@ -23,55 +31,47 @@ def load_json(path: str) -> Any:
         return json.load(f)
 
 
-@dataclass(frozen=True)
-class ModelSpec:
-    """The model dimensions the harness, the counts and the reference use."""
-    name: str
-    layers: int
-    d_model: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    norm: str            # "layernorm" (no scale or bias) | "rmsnorm"
-    norm_eps: float
-    rope_theta: float
-    tied: bool
-    dtype: str           # the type the weights are served in
+def load_file(path: str, name: str) -> ModuleType:
+    """Import the Python file at ``path`` as a module called ``name``
+    (entered in ``sys.modules``, as its dataclasses need)."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
 
-    @classmethod
-    def from_config(cls, cfg: Dict[str, Any]) -> "ModelSpec":
-        """Read the sizes from a configuration file's Hugging Face keys."""
-        heads = cfg["num_attention_heads"]
-        return cls(
-            name=cfg["name"],
-            layers=cfg["num_hidden_layers"],
-            d_model=cfg["hidden_size"],
-            heads=heads,
-            kv_heads=cfg["num_key_value_heads"],
-            head_dim=cfg.get("head_dim") or cfg["hidden_size"] // heads,
-            d_ff=cfg["intermediate_size"],
-            vocab=cfg["vocab_size"],
-            norm=cfg["norm"],
-            norm_eps=cfg.get("rms_norm_eps", cfg.get("norm_eps", 1e-5)),
-            rope_theta=float(cfg["rope_theta"]),
-            tied=bool(cfg["tie_word_embeddings"]),
-            dtype=cfg["serve_dtype"],
-        )
+
+@functools.cache
+def load_arch(name: str, arch_dir: str = ARCH_DIR) -> ModuleType:
+    """The architecture module ``<arch_dir>/<name>.py``, imported once
+    per process (its jitted functions and ``Spec`` class stay the same
+    objects for every cell that names it)."""
+    return load_file(os.path.join(arch_dir, f"{name}.py"),
+                     f"bench_arch_{name}")
 
 
 @dataclass(frozen=True)
 class Cell:
-    """One workload of BENCHMARK.json with everything it names, loaded."""
+    """One workload of BENCHMARK.json with everything it names, loaded;
+    its architecture module and model come from ``arch_dir``."""
     name: str
     chips: int
     config: Dict[str, Any]
-    model: ModelSpec
     traffic_name: str
     traffic: Dict[str, Any]
     end_to_end: List[Dict[str, Any]]
     per_layer: List[Dict[str, Any]]
+    arch_dir: str = ARCH_DIR
+
+    @property
+    def arch(self) -> ModuleType:
+        """The architecture module the configuration names."""
+        return load_arch(self.config.get("arch", "dense"), self.arch_dir)
+
+    @functools.cached_property
+    def model(self):
+        """The architecture's ``Spec`` of the configuration."""
+        return self.arch.spec(self.config)
 
     @property
     def serving(self) -> Dict[str, Any]:
@@ -104,6 +104,5 @@ def load_cell(name: str, root: str = ROOT,
     traffic = load_json(os.path.join(BENCH_DIR, "traffic",
                                      f"{w['traffic']}.json"))
     return Cell(name=name, chips=w["chips"], config=config,
-                model=ModelSpec.from_config(config),
                 traffic_name=w["traffic"], traffic=traffic,
                 end_to_end=bench["end_to_end"], per_layer=bench["per_layer"])

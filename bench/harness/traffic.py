@@ -21,7 +21,6 @@ after arrival) and a ``priority``.
 """
 from __future__ import annotations
 
-import importlib.util
 import math
 import os
 from dataclasses import dataclass
@@ -30,7 +29,7 @@ from typing import Any, Callable, Dict, List, Optional
 
 import numpy as np
 
-from harness.spec import BENCH_DIR
+from harness.spec import BENCH_DIR, load_file
 
 TRAFFIC_DIR = os.path.join(BENCH_DIR, "traffic")
 
@@ -135,9 +134,6 @@ def generator(mix: Dict[str, Any]
     name = mix.get("generator")
     if name is None:
         return generate
-    spec = importlib.util.spec_from_file_location(
-        f"bench_traffic_{name}", os.path.join(TRAFFIC_DIR, f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.generate
+    return load_file(os.path.join(TRAFFIC_DIR, f"{name}.py"),
+                     f"bench_traffic_{name}").generate
 

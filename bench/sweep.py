@@ -43,7 +43,7 @@ def main(argv=None) -> int:
     from harness.record import RunRecord, percentile
     from harness.runner import program_config, program_mesh, shape_warmup
     from harness.spec import load_cell
-    from harness.weights import program_params, root_key
+    from harness.weights import root_key
     from repro.launch.compile_cache import enable_compile_cache
     from repro.serving import LLMServer
 
@@ -55,8 +55,8 @@ def main(argv=None) -> int:
     m = cell.model
     cfg, sc = program_config(cell)
     mesh, layout = program_mesh(cell, jax.devices())
-    server = LLMServer(program_params(root_key(args.seed), m), cfg, sc,
-                       mesh=mesh, layout=layout)
+    server = LLMServer(cell.arch.program_params(root_key(args.seed), m),
+                       cfg, sc, mesh=mesh, layout=layout)
     shape_warmup(Pump(server), cell.traffic, args.seed, m.vocab)
     rows = []
     for rate in (float(r) for r in args.rates.split(",")):
@@ -79,8 +79,9 @@ def main(argv=None) -> int:
         pump.drain()
         for r in pump.records:
             r.freeze()
-        run = RunRecord(model=m, serving=cell.serving, chips=1,
-                        window=(w0, w1), setup_s=0.0, records=pump.records)
+        run = RunRecord(arch=cell.arch, model=m, serving=cell.serving,
+                        chips=1, window=(w0, w1), setup_s=0.0,
+                        records=pump.records)
         due = [r for r in pump.records if w0 <= r.due < w1]
         row = dict(rate_hz=rate, due=len(due),
                    finished=sum(r.state == "FINISHED" for r in due),

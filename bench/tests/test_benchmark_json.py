@@ -5,7 +5,7 @@ import re
 
 import pytest
 
-from harness.spec import BENCH_DIR, ROOT, load_benchmark, load_cell
+from harness.spec import BENCH_DIR, ROOT, load_benchmark, load_cell, load_json
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -67,6 +67,23 @@ def test_cell_loads(w):
 
 
 def test_reduced_lists_only_depth():
+    """``reduced`` names only the cuts the model-configs guide allows
+    (depth, the chip's share of the routed experts, a slice of the
+    vocabulary), each beside its published value, above the guide's
+    floors, and never a width."""
+    allowed = {"num_hidden_layers", "n_routed_experts", "vocab_size"}
     for c in BENCH["configs"]:
+        cfg = load_json(os.path.join(ROOT, c["file"]))
+        assert cfg.get("reduced", []) == c["reduced"], c["name"]
+        published = cfg.get("published", {})
         for key in c["reduced"]:
-            assert not re.search(r"(_dim|_rank|size|hidden|heads)$", key)
+            assert key in allowed, (c["name"], key)
+            assert not re.search(r"(_dim|_rank|hidden|heads)$", key)
+            assert published[key] > cfg[key], (c["name"], key)
+        if "num_hidden_layers" in c["reduced"]:
+            lead = cfg.get("first_k_dense_replace", 0)
+            assert cfg["num_hidden_layers"] - lead >= 4, c["name"]
+        if "n_routed_experts" in c["reduced"]:
+            assert cfg["n_routed_experts"] >= 8, c["name"]
+        if "vocab_size" in c["reduced"]:
+            assert 8 * cfg["vocab_size"] >= published["vocab_size"], c["name"]

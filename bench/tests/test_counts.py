@@ -1,8 +1,10 @@
-"""Operation and byte counts against hand-worked values."""
+"""Operation and byte counts of the dense architecture against
+hand-worked values."""
 import pytest
 
-from harness import counts
-from harness.spec import load_cell
+from harness.spec import load_arch, load_cell
+
+counts = load_arch("dense")
 
 NEMO = load_cell("nemo12b-longdecode").model
 OLMO = load_cell("olmo1b-chat").model
