@@ -16,7 +16,7 @@ from typing import Optional, Tuple
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str                      # "dense" | "moe" | "hybrid" | "ssm"
+    family: str                      # "dense" | "moe" | "mla" | "hybrid" | "ssm"
     modality: str = "text"           # "text" | "vlm" | "audio"
     num_layers: int = 0
     d_model: int = 0
@@ -32,6 +32,26 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0                # per-expert FFN width
     first_k_dense: int = 0           # leading layers that use a dense FFN
+    norm_topk_prob: bool = True      # renormalise the kept top-k gates
+    # Routed experts this instance holds, (first, count) of a contiguous
+    # range of the router's ``num_experts`` outputs; () holds them all.
+    held_experts: Tuple[int, ...] = ()
+
+    # --- latent attention (MLA, DeepSeek-V2): family "mla" ---
+    # Every layer caches one row per token, [kv_a_norm(c_kv) | rope(k_pe)]
+    # (kv_lora_rank + qk_rope_head_dim wide), shared by all heads.
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0             # 0 => a full-rank query projection
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN rope scaling; rope_factor 0 => plain rope on the rope part.
+    rope_factor: float = 0.0
+    rope_original_max_pos: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     # --- normalization / activation / positional ---
     norm_type: str = "rmsnorm"       # "rmsnorm" | "layernorm" | "nonparametric_ln"
@@ -40,6 +60,7 @@ class ModelConfig:
     positional: str = "rope"         # "rope" | "sinusoidal" | "none"
     rope_theta: float = 10_000.0
     tie_embeddings: bool = False
+    norm_eps: float = 1e-5
 
     # --- hybrid (RecurrentGemma): repeating block pattern ---
     # e.g. ("rglru", "rglru", "attn"): one attention layer per two recurrent.
@@ -63,6 +84,49 @@ class ModelConfig:
         return self.num_experts > 0
 
     @property
+    def is_latent(self) -> bool:
+        """Latent (MLA) attention: one shared cache row per token."""
+        return self.family == "mla"
+
+    @property
+    def latent_dim(self) -> int:
+        """Width of one latent cache row (0 outside the latent family)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_pool_dim(self) -> int:
+        """Width of a latent pool row: ``latent_dim`` rounded up to the
+        TPU's 128 lanes (a 576-wide array is stored 640 wide on the chip
+        anyway, and its DMAs must move whole lane tiles); zero-filled."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def expert_range(self) -> Tuple[int, int]:
+        """(first, count) of the routed experts this instance holds."""
+        return tuple(self.held_experts) or (0, self.num_experts)
+
+    def kv_elems_per_token_layer(self) -> int:
+        """Cached elements one token adds to one attention layer: K and V
+        of every kv head, or the one latent row."""
+        if self.is_latent:
+            return self.latent_dim
+        return 2 * self.num_kv_heads * self.head_dim
+
+    def partial_elems(self) -> int:
+        """Elements of one MicroAttention partial's ``o`` (all heads):
+        per-head values, or per-head latent rows."""
+        if self.is_latent:
+            return self.num_heads * self.kv_lora_rank
+        return self.num_heads * self.head_dim
+
+    def query_elems(self) -> int:
+        """Elements of the query a creditor receives (all heads): the
+        rotated query, or its absorbed latent form."""
+        if self.is_latent:
+            return self.num_heads * self.latent_dim
+        return self.num_heads * self.head_dim
+
+    @property
     def has_attention(self) -> bool:
         return self.family != "ssm"
 
@@ -83,7 +147,7 @@ class ModelConfig:
     # --- sizing helpers (used by the perf model and roofline) ----------- #
     def kv_bytes_per_token(self, bytes_per_el: int = 2) -> int:
         """KV-cache bytes per token across ALL layers (0 for O(1)-state archs)."""
-        per_layer = 2 * self.num_kv_heads * self.head_dim * bytes_per_el
+        per_layer = self.kv_elems_per_token_layer() * bytes_per_el
         n_attn = sum(1 for i in range(self.num_layers) if self.layer_kind(i) == "attn")
         return per_layer * n_attn
 
@@ -95,7 +159,14 @@ class ModelConfig:
         glu = self.activation in ("swiglu", "geglu")
         for i in range(self.num_layers):
             kind = self.layer_kind(i)
-            if kind == "attn":
+            if kind == "attn" and self.is_latent:
+                H, r = self.num_heads, self.kv_lora_rank
+                qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+                total += d * H * qk + d * self.latent_dim       # q, kv_a
+                total += r * H * (self.qk_nope_head_dim
+                                  + self.v_head_dim)            # kv_b
+                total += H * self.v_head_dim * d                # o
+            elif kind == "attn":
                 total += d * self.num_heads * hd * 2            # q, o
                 total += d * self.num_kv_heads * hd * 2         # k, v
             elif kind == "rglru":
@@ -108,7 +179,7 @@ class ModelConfig:
             # FFN
             if kind in ("attn", "rglru"):
                 if self.is_moe and i >= self.first_k_dense:
-                    n_e = self.num_experts + self.num_shared_experts
+                    n_e = self.expert_range[1] + self.num_shared_experts
                     per = (3 if glu else 2) * d * self.moe_d_ff
                     total += n_e * per + d * self.num_experts   # + router
                 elif self.d_ff:
@@ -123,7 +194,7 @@ class ModelConfig:
         glu = self.activation in ("swiglu", "geglu")
         per = (3 if glu else 2) * d * self.moe_d_ff
         n_moe_layers = self.num_layers - self.first_k_dense
-        inactive = (self.num_experts - self.top_k) * per * n_moe_layers
+        inactive = (self.expert_range[1] - self.top_k) * per * n_moe_layers
         return self.param_count() - inactive
 
 

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.flash_prefill import flash_prefill_kernel
+from repro.kernels.latent_decode import paged_latent_attention_kernel
 from repro.kernels.micro_attn_decode import paged_micro_attention_kernel
 from repro.kernels.micro_attn_prefill import \
     paged_prefill_micro_attention_kernel
@@ -262,3 +263,84 @@ def paged_prefill_attention_ranks(q, pools_k, pools_v, tables, tails, *,
         lambda pk, pv, tb, tl: paged_prefill_attention(
             q, pk, pv, tb, tl, scale=scale, backend=backend)
     )(pools_k, pools_v, tables, tails)
+
+
+# Bytes of one tile buffer of the paged latent decode kernel (two are
+# live, double-buffered): 64 blocks of deepseek-v2-lite's (16, 576) bf16
+# rows, 1,024 tokens a tile.
+_LATENT_TILE_BYTES = 2 * 1024 * 1024
+
+
+def paged_latent_attention_jnp(q, pool, layer, table, tail_len, *, rank,
+                               scale):
+    """Pure-jnp paged latent partial: the gather form of
+    ``paged_latent_attention``, with the same dtypes (scores and the
+    value product in the pool's dtype, float32 accumulation). Gathers
+    only the table's blocks of layer ``layer`` from the stacked pool."""
+    L, NB, bs, W = pool.shape
+    R, MB = table.shape
+    idx = jnp.where(table >= 0, layer * NB + table, 0)
+    rows = pool.reshape(L * NB, bs, W)[idx].reshape(R, MB * bs, W)
+    nblk = jnp.sum(table >= 0, axis=-1)
+    limit = (nblk - 1) * bs + tail_len
+    valid = jnp.arange(MB * bs)[None] < limit[:, None]          # [R, S]
+    s = jnp.einsum("rhw,rsw->rhs", q.astype(pool.dtype), rows,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(valid[:, None], s, -jnp.inf)
+    m = jnp.max(s, axis=-1)
+    shift = jnp.where(jnp.isneginf(m), 0.0, m)
+    p = jnp.exp(s - shift[..., None])
+    o = jnp.einsum("rhs,rsc->rhc", p.astype(pool.dtype), rows[..., :rank],
+                   preferred_element_type=jnp.float32)
+    return o, m, jnp.sum(p, axis=-1)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "scale", "interpret",
+                                             "backend"))
+def paged_latent_attention(q, pool, layer, table, tail_len, *, rank, scale,
+                           interpret=None, backend=None):
+    """Paged latent (MLA) MicroAttention partial of one rank (decode).
+
+    q [R,H,W] absorbed queries; pool [L,NB,bs,W] the rank's whole
+    stacked latent pool, read at layer ``layer`` (a scalar) in place;
+    table [R,MB] (valid prefix in sequence order, -1 padded); tail_len
+    [R] valid tokens in each row's last slot. A row's first ``rank``
+    entries are its value. ``backend``: "pallas" (kernel; interpret mode
+    off-TPU) or "jnp" (the gather form); None picks pallas on TPU and
+    jnp elsewhere. Returns (o [R,H,rank] f32 unnormalized, m [R,H] f32,
+    l [R,H] f32).
+    """
+    table = table.astype(jnp.int32)
+    tail_len = tail_len.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
+    if backend is None:
+        backend = "pallas" if (_on_tpu() or interpret is not None) else "jnp"
+    if backend == "jnp":
+        return paged_latent_attention_jnp(q, pool, layer, table, tail_len,
+                                          rank=rank, scale=scale)
+    _, _, bs, W = pool.shape
+    pb = 1
+    while (2 * pb <= table.shape[-1]
+           and 2 * pb * bs * W * pool.dtype.itemsize <= _LATENT_TILE_BYTES):
+        pb *= 2
+    nblk = jnp.sum(table >= 0, axis=-1).astype(jnp.int32)
+    o, m, l = paged_latent_attention_kernel(
+        q, pool, layer.reshape(1), table, nblk, tail_len, rank=rank,
+        scale=scale, tile_blocks=pb, interpret=_interpret(interpret))
+    return o, m[:, 0], l[:, 0]
+
+
+def write_latent_rows(pool: jax.Array, rows: jax.Array, block, off
+                      ) -> jax.Array:
+    """Write rows [L, n, W] at (block [n], off [n]) of every layer of the
+    stacked latent pool [L, NB, bs, W] that ``paged_latent_attention``
+    reads; block NB (out of range) writes nothing. The scatter addresses
+    the pool as one [L * NB * bs, W] table of rows: indexed by block and
+    offset, the TPU compiler would copy the whole pool into another
+    layout and back around it."""
+    L, NB, bs, W = pool.shape
+    idx = (jnp.arange(L)[:, None] * NB + block[None]) * bs + off[None]
+    idx = jnp.where(block[None] < NB, idx, L * NB * bs)
+    flat = pool.reshape(L * NB * bs, W).at[idx.reshape(-1)].set(
+        rows.reshape(-1, W).astype(pool.dtype), mode="drop")
+    return flat.reshape(pool.shape)

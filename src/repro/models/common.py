@@ -28,7 +28,8 @@ def init_norm(cfg: ModelConfig, dim: int):
     raise ValueError(cfg.norm_type)
 
 
-def apply_norm(params, x, cfg: ModelConfig, eps: float = 1e-5):
+def apply_norm(params, x, cfg: ModelConfig, eps: Optional[float] = None):
+    eps = cfg.norm_eps if eps is None else eps
     xf = x.astype(jnp.float32)
     if cfg.norm_type == "rmsnorm":
         xf = xf * jax.lax.rsqrt(jnp.mean(xf * xf, -1, keepdims=True) + eps)

@@ -6,6 +6,8 @@ One entry point for all 10 assigned architectures. Layer stacks are
 
   dense   — [ln1, attn, ln2, ffn] x L                  (scan)
   moe     — first_k_dense dense layers + [attn, moe] x L'  (scan)
+  mla     — the moe layout with latent attention (models/mla.py;
+            served on the paged path only)
   hybrid  — repeating block_pattern groups (+ leftover)    (scan of groups)
   ssm     — (slstm_every-1 mLSTM + 1 sLSTM) groups         (scan of groups)
 
@@ -44,8 +46,13 @@ from repro.models.xlstm import (MLstmState, SLstmState, apply_mlstm_block,
 def _init_attn_layer(key, cfg: ModelConfig, d_ff: Optional[int] = None,
                      moe: bool = False):
     ks = jax.random.split(key, 4)
+    if cfg.is_latent:
+        from repro.models.mla import init_mla
+        attn = init_mla(ks[0], cfg)
+    else:
+        attn = init_attention(ks[0], cfg)
     p = {"ln1": init_norm(cfg, cfg.d_model),
-         "attn": init_attention(ks[0], cfg),
+         "attn": attn,
          "ln2": init_norm(cfg, cfg.d_model)}
     if moe:
         p["moe"] = init_moe(ks[1], cfg)
@@ -75,15 +82,18 @@ def init_params(key, cfg: ModelConfig) -> Dict[str, Any]:
     if cfg.family == "dense":
         p["layers"] = jax.vmap(lambda k: _init_attn_layer(k, cfg))(
             jax.random.split(ks[2], cfg.num_layers))
-    elif cfg.family == "moe":
-        nd = cfg.first_k_dense
+    elif cfg.family in ("moe", "mla"):
+        # The latent family stacks its layers as the moe family does:
+        # leading dense layers, then expert layers (none without experts).
+        nd = cfg.first_k_dense if cfg.is_moe else cfg.num_layers
         if nd:
             p["dense_layers"] = jax.vmap(
                 lambda k: _init_attn_layer(k, cfg, d_ff=cfg.d_ff))(
                 jax.random.split(ks[3], nd))
-        p["moe_layers"] = jax.vmap(
-            lambda k: _init_attn_layer(k, cfg, moe=True))(
-            jax.random.split(ks[2], cfg.num_layers - nd))
+        if cfg.num_layers > nd:
+            p["moe_layers"] = jax.vmap(
+                lambda k: _init_attn_layer(k, cfg, moe=True))(
+                jax.random.split(ks[2], cfg.num_layers - nd))
     elif cfg.family == "hybrid":
         pat = cfg.block_pattern
         n_groups = cfg.num_layers // len(pat)

@@ -39,7 +39,8 @@ def init_moe(key, cfg: ModelConfig):
                        * std_i).astype(dtype)
         return p
 
-    p = {"router": dense_init(ks[0], d, E, dtype), "experts": bank(ks[1], E)}
+    p = {"router": dense_init(ks[0], d, E, dtype),
+         "experts": bank(ks[1], cfg.expert_range[1])}
     if SE:
         p["shared"] = bank(ks[2], SE)
     return p
@@ -81,39 +82,65 @@ def moe_capacity(n_tokens: int, cfg: ModelConfig,
     return max(cfg.top_k, min(n_tokens, c))
 
 
-def _dispatch_combine(xt, params, cfg: ModelConfig, C: int,
-                      expert_fn) -> jax.Array:
-    """Sort-based dispatch for ONE token group. xt: [N, d] -> [N, d]."""
-    E, topk = cfg.num_experts, cfg.top_k
-    N, d = xt.shape
+def route(params, xt, cfg: ModelConfig):
+    """Router of ONE token group, xt [N, d]: the softmax over all
+    ``num_experts`` outputs, its top-k (gates [N, k] float32, expert ids
+    [N, k]), the kept gates renormalised unless ``norm_topk_prob`` is
+    off."""
     logits = (xt @ params["router"]).astype(jnp.float32)      # [N, E]
-    gates, idx = jax.lax.top_k(jax.nn.softmax(logits, -1), topk)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    gates, idx = jax.lax.top_k(jax.nn.softmax(logits, -1), cfg.top_k)
+    if cfg.norm_topk_prob:
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    return gates, idx
 
-    flat_e = idx.reshape(-1)                                  # [N*k]
+
+def held_assignments(idx, cfg: ModelConfig) -> jax.Array:
+    """Per row, how many of its top-k expert ids [N, k] fall in the
+    held range (int32 [N])."""
+    lo, n = cfg.expert_range
+    return jnp.sum((idx >= lo) & (idx < lo + n), axis=-1, dtype=jnp.int32)
+
+
+def _dispatch_combine(xt, gates, idx, cfg: ModelConfig, C: int,
+                      expert_fn) -> jax.Array:
+    """Sort-based dispatch for ONE token group to the held experts:
+    every assignment outside ``cfg.expert_range`` is dropped (weight 0,
+    no capacity), the rest run on the held bank. xt [N, d], gates and
+    expert ids idx [N, k] from ``route`` -> [N, d]."""
+    lo, n = cfg.expert_range
+    N, d = xt.shape
+    topk = cfg.top_k
+    local = idx.reshape(-1) - lo                              # [N*k]
+    flat_e = jnp.where((local >= 0) & (local < n), local, n)  # n = dropped
     flat_t = jnp.repeat(jnp.arange(N, dtype=jnp.int32), topk)
     flat_g = gates.reshape(-1)
     order = jnp.argsort(flat_e)                               # stable
     se, st, sg = flat_e[order], flat_t[order], flat_g[order]
 
-    counts = jnp.zeros((E,), jnp.int32).at[flat_e].add(1)
+    counts = jnp.zeros((n + 1,), jnp.int32).at[flat_e].add(1)
     starts = jnp.cumsum(counts) - counts                      # exclusive
     pos = jnp.arange(N * topk, dtype=jnp.int32) - starts[se]
-    keep = pos < C
+    keep = (pos < C) & (se < n)
     safe_p = jnp.where(keep, pos, 0)
+    safe_e = jnp.where(keep, se, 0)
 
     tok = xt[st] * keep[:, None].astype(xt.dtype)             # [N*k, d]
-    buf = jnp.zeros((E, C, d), xt.dtype).at[se, safe_p].add(tok)
-    y_buf = expert_fn(buf)                                    # [E, C, d]
+    buf = jnp.zeros((n, C, d), xt.dtype).at[safe_e, safe_p].add(tok)
+    y_buf = expert_fn(buf)                                    # [n, C, d]
     w = (sg * keep).astype(xt.dtype)
     return jnp.zeros((N, d), xt.dtype).at[st].add(
-        y_buf[se, safe_p] * w[:, None])
+        y_buf[safe_e, safe_p] * w[:, None])
 
 
 def apply_moe(params, x: jax.Array, cfg: ModelConfig,
               capacity_factor: float = 1.25,
-              ep_groups: int = 0) -> jax.Array:
+              ep_groups: int = 0, with_held: bool = False):
     """x: [B, T, d] -> [B, T, d]. Top-k routed + always-on shared experts.
+
+    An instance that holds a share of the experts (``cfg.held_experts``)
+    routes over all of them and computes only its own: the rest of each
+    row's top-k is dropped. ``with_held`` also returns, per row, how
+    many of its top-k assignments landed on held experts (int32 [B, T]).
 
     ``ep_groups > 1`` enables the 2D expert-parallel formulation for mesh
     execution (production axes "data" x "model"): the token stream splits
@@ -132,7 +159,10 @@ def apply_moe(params, x: jax.Array, cfg: ModelConfig,
     xt = x.reshape(-1, d)                                     # [N, d]
     N = xt.shape[0]
 
+    held = None
     if ep_groups and ep_groups > 1 and N % ep_groups == 0:
+        assert cfg.expert_range == (0, E), \
+            "an expert share runs without the expert-parallel groups"
         G = ep_groups
         Ng = N // G
         Cg = moe_capacity(Ng, cfg, capacity_factor)
@@ -152,10 +182,7 @@ def apply_moe(params, x: jax.Array, cfg: ModelConfig,
         E_, topk = cfg.num_experts, cfg.top_k
 
         def phase1(xt_i):
-            logits = (xt_i @ params["router"]).astype(jnp.float32)
-            gates, idx = jax.lax.top_k(jax.nn.softmax(logits, -1), topk)
-            gates = gates / jnp.maximum(gates.sum(-1, keepdims=True),
-                                        1e-9)
+            gates, idx = route(params, xt_i, cfg)
             flat_e = idx.reshape(-1)
             flat_t = jnp.repeat(jnp.arange(Ng, dtype=jnp.int32), topk)
             flat_g = gates.reshape(-1)
@@ -184,14 +211,21 @@ def apply_moe(params, x: jax.Array, cfg: ModelConfig,
         y = jax.vmap(phase2)(y_buf_g, xg, meta).reshape(N, d)
     else:
         C = moe_capacity(N, cfg, capacity_factor)
-        y = _dispatch_combine(xt, params, cfg, C,
+        gates, idx = route(params, xt, cfg)
+        held = held_assignments(idx, cfg)
+        y = _dispatch_combine(xt, gates, idx, cfg, C,
                               lambda buf: _expert_ffn(params["experts"],
                                                       buf, cfg))
 
     if cfg.num_shared_experts:
         xs = jnp.broadcast_to(xt, (cfg.num_shared_experts, N, d))
         y = y + _expert_ffn(params["shared"], xs, cfg).sum(0).astype(xt.dtype)
-    return y.reshape(B, T, d).astype(x.dtype)
+    y = y.reshape(B, T, d).astype(x.dtype)
+    if not with_held:
+        return y
+    if held is None:
+        held = jnp.full((N,), cfg.top_k, jnp.int32)
+    return y, held.reshape(B, T)
 
 
 def moe_aux_loss(params, x: jax.Array, cfg: ModelConfig) -> jax.Array:

@@ -335,6 +335,12 @@ def paged_trace_count() -> int:
     return _PAGED_TRACE_COUNT
 
 
+def note_paged_trace() -> None:
+    """Count one trace of a paged decode step (any attention family)."""
+    global _PAGED_TRACE_COUNT
+    _PAGED_TRACE_COUNT += 1
+
+
 def _paged_partial(q, pk, pv, table, tail, backend):
     """One rank's MicroAttention partial over its pool (paper Eq. 2)."""
     if backend == "pallas":
@@ -408,8 +414,7 @@ def _paged_attn_decode(lp, x, lens, pk, pv, rks, rvs, tables, tails,
 def _decode_step_paged_jit(params, tokens, lens, pool_k, pool_v,
                            remote_k, remote_v, tables, tails,
                            write_block, write_off, *, cfg, backend):
-    global _PAGED_TRACE_COUNT
-    _PAGED_TRACE_COUNT += 1
+    note_paged_trace()
     x = embed_tokens(params, cfg, tokens[:, None], None,
                      positions=lens[:, None])
 
@@ -483,6 +488,12 @@ def prefill_chunk_trace_count() -> int:
     return _PREFILL_CHUNK_TRACE_COUNT
 
 
+def note_prefill_chunk_trace() -> None:
+    """Count one trace of a paged prefill chunk step (any family)."""
+    global _PREFILL_CHUNK_TRACE_COUNT
+    _PREFILL_CHUNK_TRACE_COUNT += 1
+
+
 def _chunk_attn_paged(lp, x, positions, valid, pk, pv, rks, rvs,
                       tables, tails, write_block, write_off, cfg, backend):
     """One layer of the streaming-prefill step for one prompt chunk.
@@ -530,8 +541,7 @@ def _prefill_chunk_paged_jit(params, tokens, positions, valid, last_idx,
                              pool_k, pool_v, remote_k, remote_v,
                              tables, tails, write_block, write_off, *,
                              cfg, backend):
-    global _PREFILL_CHUNK_TRACE_COUNT
-    _PREFILL_CHUNK_TRACE_COUNT += 1
+    note_prefill_chunk_trace()
     x = embed_tokens(params, cfg, tokens, None, positions)
 
     def make_body(moe):

@@ -63,7 +63,8 @@ from repro.serving.engine import InstanceEngine
 from repro.serving.faults import FaultInjector, FaultPlan, FaultStats
 from repro.serving.gmanager import GManager
 from repro.serving.hosttier import HostKVTier
-from repro.serving.kvpool import rows_for_token_range
+from repro.serving.kvpool import (rows_for_token_range, rows_nbytes,
+                                  rows_slice)
 from repro.serving.perfmodel import InstancePerfModel
 from repro.serving.prefixcache import RadixPrefixCache
 from repro.serving.protocol import MoveKVCache, MoveLeg, MoveResult
@@ -153,7 +154,8 @@ class PrefixSink:
     def write(self, t0: int, k, v) -> None:
         """Scatter global prefix rows [t0, t0 + n) into creditor pools.
 
-        k/v: [L, n, K, hd] — one prefill chunk's creditor-bound rows.
+        k/v: [L, n, K, hd] — one prefill chunk's creditor-bound rows
+        (the latent family: k [L, n, W], v None).
         The scatters are DISPATCHED here and staged on the cluster's
         ``AsyncStager``; they complete behind the next chunk's compute
         (or the cluster's decode) and are only drained at ``flush()``,
@@ -169,8 +171,8 @@ class PrefixSink:
                                             lo - start, hi - start)
             eng = self._cluster.engines[d]
             eng.host_kv_rows(
-                self._req_id, blk, off,
-                k[:, lo - t0:hi - t0], v[:, lo - t0:hi - t0])
+                self._req_id, blk, off, rows_slice(k, lo - t0, hi - t0),
+                rows_slice(v, lo - t0, hi - t0))
             self._cluster.stager.stage((eng.pool_k, eng.pool_v))
 
     def flush(self) -> None:
@@ -227,6 +229,9 @@ class Cluster:
         # decode/prefill run decode_step_global / prefill_chunk_global.
         self.mesh = mesh
         self.gpool = None
+        if config.global_pool and cfg.is_latent:
+            raise ValueError("the latent family serves from per-instance "
+                             "pools only; set global_pool=False")
         if config.global_pool and cfg.family in ("dense", "moe"):
             from repro.serving.globalpool import GlobalKVPool
             pool_axes = (tuple(layout.pool_axes) if layout is not None
@@ -566,7 +571,7 @@ class Cluster:
                 dst.host_kv(mv.req_id, blocks, k, v)
                 self.stager.stage((dst.pool_k, dst.pool_v))
                 src.rmanager.move_out_prefix(mv.req_id, n)
-                nbytes = int(k.size + v.size) * k.dtype.itemsize
+                nbytes = rows_nbytes(k, v)
             if dst_id != owner.inst_id:
                 insts = owner.remote_insts.setdefault(mv.req_id, [])
                 if dst_id not in insts:

@@ -34,9 +34,13 @@ bytes live in the instance's device-resident block pool
 ``max_local_len`` survives as the per-request LOCAL QUOTA (the paper's
 instance-local budget): when a request's local span approaches it the
 cluster ships prefix blocks to a creditor and decoding continues with
-the multi-rank paged step. Non-attention families (hybrid/ssm) keep the
-dense ``prefill()`` + ``DecodeState`` path — their recurrent state is
-O(1) per request and never pools.
+the multi-rank paged step. The latent family (``mla``) pools the same
+way with ONE stacked pool of latent rows, ``pool_k: [L, num_blocks,
+block_size, W]``, and no ``pool_v`` (None): its blocks move, spill and
+restore through the same code, which carries the absent V rows as None
+(``models/mla.py`` holds its steps). Non-attention families (hybrid/ssm)
+keep the dense ``prefill()`` + ``DecodeState`` path — their recurrent
+state is O(1) per request and never pools.
 
 ZERO-COPY DISCIPLINE: the pool tensors (and the sampling PRNG key) are
 DONATED into every jitted step and updater — each engine threads exactly
@@ -60,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.models.mla import decode_step_latent, prefill_chunk_latent
 from repro.models.model import DecodeState, decode_step, init_decode_state
 from repro.models.prefill import (decode_step_paged, prefill,
                                   prefill_chunk_paged, repack_ring,
@@ -68,6 +73,7 @@ from repro.serving.sharded_step import (decode_step_global,
                                         prefill_chunk_global)
 from repro.serving.kvpool import (build_local_tables, prefix_tables,
                                   read_pool_rows, rows_for_token_range,
+                                  rows_nbytes, rows_slice,
                                   scatter_pool_rows, table_bucket,
                                   write_pool_rows)
 from repro.serving.request import Request, RequestState
@@ -201,12 +207,20 @@ class InstanceEngine:
             self._key = jax.device_put(
                 self._key, NamedSharding(gpool.mesh, P()))
         self._finished_events: List[int] = []
-        self._can_pool = cfg.family in ("dense", "moe")
+        self._can_pool = cfg.family in ("dense", "moe", "mla")
         self._pool_k = self._pool_v = None
+        # Held-expert counts of the last decode step ([B] on the device)
+        # and its active slots: read back with the sampled tokens.
+        self._routes = None
         if self._can_pool:
             assert max_local_len >= 2 * block_size, \
                 "local quota must cover at least two blocks"
-            if gpool is None:
+            if cfg.is_latent:
+                assert gpool is None, "the latent family has no global pool"
+                self._pool_k = jnp.zeros(
+                    (cfg.num_layers, pool_blocks, block_size,
+                     cfg.latent_pool_dim), jnp.dtype(cfg.dtype))
+            elif gpool is None:
                 L, K, hd = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
                 dt = jnp.dtype(cfg.dtype)
                 # THE serving KV store: every local or hosted byte
@@ -436,10 +450,10 @@ class InstanceEngine:
             self.gpool.scatter_rows(self.inst_id, blk, off,
                                     k[:, :n_rows], v[:, :n_rows])
             return
-        k = read_pool_rows(self.pool_k, [src_blk],
-                           self.block_size)[:, :n_rows]
-        v = read_pool_rows(self.pool_v, [src_blk],
-                           self.block_size)[:, :n_rows]
+        k = rows_slice(read_pool_rows(self.pool_k, [src_blk],
+                                      self.block_size), 0, n_rows)
+        v = rows_slice(read_pool_rows(self.pool_v, [src_blk],
+                                      self.block_size), 0, n_rows)
         self.pool_k = scatter_pool_rows(self.pool_k, blk, off, k)
         self.pool_v = scatter_pool_rows(self.pool_v, blk, off, v)
 
@@ -550,10 +564,9 @@ class InstanceEngine:
             return logits
         if sink is not None:
             self.remote_insts[rid] = list(sink.rank_ids)
-            L, K, hd = (self.cfg.num_layers, self.cfg.num_kv_heads,
-                        self.cfg.head_dim)
             itemsize = jnp.dtype(self.cfg.dtype).itemsize
-            self.stats.kv_moved += int(2 * L * n_over * K * hd) * itemsize
+            self.stats.kv_moved += n_over * self.cfg.kv_bytes_per_token(
+                itemsize)
             # Record the GLOBAL chain — cached + striped creditor +
             # local tail blocks in token order (with a sink, n_cached is
             # always block-aligned: a full-prompt COW hit implies
@@ -637,19 +650,27 @@ class InstanceEngine:
                                           table_bucket(needed))
             # Re-read creditor pools every chunk: sink writes rebind
             # the peers' pool tensors between steps.
-            remote = tuple((self.peers[d].pool_k, self.peers[d].pool_v)
-                           for d in cred_ids)
-            logits, self.pool_k, self.pool_v, k_c, v_c = \
-                prefill_chunk_paged(
-                    self.params, self.cfg, toks, t0, n_valid,
-                    self.pool_k, self.pool_v, tables, tails, wblk, woff,
-                    remote_pools=remote)
+            if self.cfg.is_latent:
+                logits, self.pool_k, k_c = prefill_chunk_latent(
+                    self.params, self.cfg, toks, t0, n_valid, self.pool_k,
+                    tables, tails, wblk, woff,
+                    remote_pools=tuple(self.peers[d].pool_k
+                                       for d in cred_ids))
+                v_c = None
+            else:
+                remote = tuple((self.peers[d].pool_k, self.peers[d].pool_v)
+                               for d in cred_ids)
+                logits, self.pool_k, self.pool_v, k_c, v_c = \
+                    prefill_chunk_paged(
+                        self.params, self.cfg, toks, t0, n_valid,
+                        self.pool_k, self.pool_v, tables, tails, wblk,
+                        woff, remote_pools=remote)
             if sink is not None and t0 < cred_end:
                 hi = min(t1, cred_end)
-                sink.write(t0, k_c[:, :hi - t0], v_c[:, :hi - t0])
+                sink.write(t0, rows_slice(k_c, 0, hi - t0),
+                           rows_slice(v_c, 0, hi - t0))
             self.stats.admit_stage_bytes = max(
-                self.stats.admit_stage_bytes,
-                int((k_c.size + v_c.size) * k_c.dtype.itemsize))
+                self.stats.admit_stage_bytes, rows_nbytes(k_c, v_c))
         if sink is not None:
             # Table-commit point: the creditor spans become part of this
             # request's decode view now, so the staged (possibly still
@@ -741,9 +762,25 @@ class InstanceEngine:
                     self._key, logits, temps, jnp.asarray(ks, jnp.int32))
             else:
                 toks, self._key = _sample_batch(self._key, logits, temps)
-        # The host waits here for the device to finish the step.
+        # The host waits here for the device to finish the step; the
+        # decode step's held-expert counts come back in the same copy.
         with self.tracer.span("serve.readback"):
+            if self._routes is None:
+                return np.asarray(toks)
+            held, active = self._routes
+            self._routes = None
+            toks, held = jax.device_get((toks, held))
+            self._count_routes(np.asarray(held), active)
             return np.asarray(toks)
+
+    def _count_routes(self, held: np.ndarray, active: np.ndarray) -> None:
+        """Count one decode step's routed (token, expert) assignments
+        and those that landed on held experts, over its active slots."""
+        cfg = self.cfg
+        n_moe = cfg.num_layers - cfg.first_k_dense
+        self.tracer.count("moe.routed",
+                          int(active.sum()) * cfg.top_k * n_moe)
+        self.tracer.count("moe.held", int(held[active].sum()))
 
     def _emit(self, req: Request, tok: int) -> None:
         req.output.append(tok)
@@ -915,23 +952,36 @@ class InstanceEngine:
         # discipline — self.pool_k/v are the only live references).
         ptr = buffer_ptr(self.pool_k)
         with self.tracer.span("serve.decode"):
-            logits, self.pool_k, self.pool_v = decode_step_paged(
-                self.params, self.cfg, tokens, lens, self.pool_k,
-                self.pool_v, tables, tails, wblk, woff,
-                remote_pools=remote_pools)
+            if self.cfg.is_latent:
+                logits, self.pool_k, held = decode_step_latent(
+                    self.params, self.cfg, tokens, lens, self.pool_k,
+                    tables, tails, wblk, woff,
+                    remote_pools=tuple(p for p, _ in remote_pools))
+                if self.cfg.is_moe:
+                    self._routes = (held, wblk < NB)
+            else:
+                logits, self.pool_k, self.pool_v = decode_step_paged(
+                    self.params, self.cfg, tokens, lens, self.pool_k,
+                    self.pool_v, tables, tails, wblk, woff,
+                    remote_pools=remote_pools)
         if ptr is not None and buffer_ptr(self.pool_k) != ptr:
             self.stats.pool_copy_steps += 1
+        self._count_merge_traffic(running)
+        return logits
 
-        # Account the paper's per-step merge traffic — q + (o, m, l) —
-        # once per (request, creditor) span entry, matching the per-rank
-        # partial exchanges a real deployment would make.
-        H, hd = self.cfg.num_heads, self.cfg.head_dim
-        L = self.cfg.num_layers
+    def _count_merge_traffic(self, running: List[Request]) -> None:
+        """Account the paper's per-step merge traffic — q + (o, m, l) —
+        once per (request, creditor) span entry, matching the per-rank
+        partial exchanges a real deployment would make, and count the
+        creditor partials the step computed (one per entry and layer)."""
+        cfg = self.cfg
+        H, L = cfg.num_heads, cfg.num_layers
         entries = sum(len(self.remote_insts.get(r.req_id, ()))
                       for r in running)
         self.stats.query_shipped += int(
-            entries * L * (H * hd * 2 + H * hd * 4 + 2 * H * 4))
-        return logits
+            entries * L * (cfg.query_elems() * 2 + cfg.partial_elems() * 4
+                           + 2 * H * 4))
+        self.tracer.count("attn.creditor_partials", entries * L)
 
     def _step_paged_global(self) -> Optional[jnp.ndarray]:
         """One decode iteration over the GLOBAL pool tensor.
@@ -988,13 +1038,7 @@ class InstanceEngine:
                 pool_axes=gpool.pool_axes)
         if ptr is not None and buffer_ptr(gpool.k) != ptr:
             self.stats.pool_copy_steps += 1
-
-        H, hd = self.cfg.num_heads, self.cfg.head_dim
-        L = self.cfg.num_layers
-        entries = sum(len(self.remote_insts.get(r.req_id, ()))
-                      for r in running)
-        self.stats.query_shipped += int(
-            entries * L * (H * hd * 2 + H * hd * 4 + 2 * H * 4))
+        self._count_merge_traffic(running)
         return logits
 
     def step(self) -> int:
@@ -1058,7 +1102,7 @@ class InstanceEngine:
             return k[:, None], v[:, None]
         k = read_pool_rows(self.pool_k, blocks, self.block_size)
         v = read_pool_rows(self.pool_v, blocks, self.block_size)
-        return k[:, None], v[:, None]        # [L, 1, n*bs, K, hd]
+        return k[:, None], (None if v is None else v[:, None])
 
     # --- prefix-cache block transport ----------------------------------#
     def read_block_rows(self, block: int):
@@ -1080,23 +1124,25 @@ class InstanceEngine:
             return
         self.pool_k = write_pool_rows(self.pool_k, [block],
                                       jnp.asarray(k), self.block_size)
-        self.pool_v = write_pool_rows(self.pool_v, [block],
-                                      jnp.asarray(v), self.block_size)
+        self.pool_v = write_pool_rows(
+            self.pool_v, [block], None if v is None else jnp.asarray(v),
+            self.block_size)
 
     # --- creditor side -------------------------------------------------#
     def host_kv(self, req_id: int, blocks: List[int], k, v) -> None:
         """Write an arriving span's rows into already-committed blocks.
 
         k/v: [L, 1, n, K, hd] with n == len(blocks) * block_size (spans
-        are always whole blocks).
+        are always whole blocks); v is None for the latent family.
         """
         if self.gpool is not None:
             self.gpool.write_blocks(self.inst_id, blocks, k[:, 0], v[:, 0])
             return
         self.pool_k = write_pool_rows(self.pool_k, blocks, k[:, 0],
                                       self.block_size)
-        self.pool_v = write_pool_rows(self.pool_v, blocks, v[:, 0],
-                                      self.block_size)
+        self.pool_v = write_pool_rows(
+            self.pool_v, blocks, None if v is None else v[:, 0],
+            self.block_size)
 
     def host_kv_rows(self, req_id: int, block_ids, offsets, k, v) -> None:
         """Scatter a streaming-prefill span's rows into already-committed
@@ -1232,8 +1278,7 @@ class InstanceEngine:
             k, v = frames[idx]
             idx += 1
             self.write_block_rows(b, k, v)
-            self.stats.host_prefetch_bytes += int(
-                k.size * k.dtype.itemsize + v.size * v.dtype.itemsize)
+            self.stats.host_prefetch_bytes += rows_nbytes(k, v)
         assert idx == len(frames), "chain frames != reserved blocks"
         if sink is not None:
             chain = [(inst, b) for inst, _start, blks in sink.spans

@@ -40,6 +40,7 @@ import numpy as np
 
 from repro.serving.faults import (FrameCorruptionError, TransferError,
                                   backoff_delay_s)
+from repro.serving.kvpool import rows_nbytes
 from repro.serving.tracing import Tracer
 
 
@@ -143,7 +144,8 @@ class HostKVTier:
     def put(self, key: Any, k_dev: Any, v_dev: Any) -> bool:
         """Spill one block's device rows to host, asynchronously.
 
-        ``k_dev``/``v_dev``: [L, block_size, K, hd] device arrays that
+        ``k_dev``/``v_dev``: [L, block_size, K, hd] device arrays (the
+        latent family: k [L, block_size, W] and v None) that
         do NOT alias the pool (a gather result). Returns False when the
         tier cannot make room (capacity 0 or everything pinned) — the
         caller then simply drops the block, the pre-tier behavior.
@@ -158,7 +160,7 @@ class HostKVTier:
                 not self._evict_down(self.capacity - 1):
             self.stats.rejected += 1
             return False
-        for a in (k_dev, v_dev):
+        for a in (x for x in (k_dev, v_dev) if x is not None):
             try:
                 a.copy_to_host_async()
             except Exception:
@@ -166,9 +168,7 @@ class HostKVTier:
         self._pending[key] = (k_dev, v_dev)
         self._touch(key)
         self.stats.spills += 1
-        self.stats.spilled_bytes += int(
-            k_dev.size * k_dev.dtype.itemsize
-            + v_dev.size * v_dev.dtype.itemsize)
+        self.stats.spilled_bytes += rows_nbytes(k_dev, v_dev)
         if self.used_blocks > int(self.high * self.capacity):
             self._evict_down(int(self.low * self.capacity))
         return True
@@ -189,15 +189,16 @@ class HostKVTier:
     def _finalize(self, key: Any, k: Any, v: Any) -> None:
         # The landed host bytes are the frame of record: the content
         # hash every later fetch is verified against is taken HERE.
-        frame = (np.asarray(k), np.asarray(v))
+        frame = (np.asarray(k), None if v is None else np.asarray(v))
         self._frames[key] = frame
         if self.verify:
             self._sums[key] = self._checksum(frame)
 
     @staticmethod
     def _checksum(frame: Tuple[np.ndarray, np.ndarray]) -> int:
-        return zlib.crc32(frame[1].tobytes(),
-                          zlib.crc32(frame[0].tobytes()))
+        crc = zlib.crc32(frame[0].tobytes())
+        return crc if frame[1] is None else zlib.crc32(frame[1].tobytes(),
+                                                       crc)
 
     @staticmethod
     def _is_ready(a: Any) -> bool:
@@ -257,8 +258,7 @@ class HostKVTier:
                 f"host frame {key!r} failed content-hash verification")
         self._touch(key)
         self.stats.fetches += 1
-        self.stats.fetched_bytes += int(
-            frame[0].nbytes + frame[1].nbytes)
+        self.stats.fetched_bytes += rows_nbytes(*frame)
         return frame
 
     def _corrupt(self, key: Any) -> None:

@@ -4,7 +4,9 @@ Each serving rank (an ``InstanceEngine`` in the in-process cluster, or an
 entry on the ``data``/``model`` mesh axis) owns a fixed pool of
 ``num_blocks`` blocks of ``block_size`` tokens. Since the pool refactor
 this is where ALL serving KV bytes live: each engine holds device tensors
-``pool_k/pool_v: [L, num_blocks, block_size, K, hd]``, and the host-side
+``pool_k/pool_v: [L, num_blocks, block_size, K, hd]`` (the latent family:
+one ``pool_k: [L, num_blocks, block_size, W]`` of latent rows and no
+``pool_v``, which the row helpers here carry as None), and the host-side
 allocator here hands out the block ids that index them. Per-request
 *local tables* (sequence-ordered local block ids, -1 padded, built by
 ``build_local_tables``) are what the paged MicroAttention step consumes.
@@ -36,6 +38,8 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.kernels.ops import write_latent_rows
 
 
 class BlockAllocator:
@@ -266,16 +270,28 @@ def _scatter_pool_rows_jit(pool, blk, off, rows):
     return pool.at[:, blk, off].set(rows.astype(pool.dtype))
 
 
-def write_pool_rows(pool: jax.Array, block_ids: Sequence[int],
-                    rows: jax.Array, block_size: int) -> jax.Array:
+@functools.partial(jax.jit, donate_argnames=("pool",))
+def _scatter_latent_rows_jit(pool, blk, off, rows):
+    # Row-addressed writes into a latent pool go through its flat row
+    # view, which the TPU updates in place.
+    return write_latent_rows(pool, rows, blk, off)
+
+
+def write_pool_rows(pool: Optional[jax.Array], block_ids: Sequence[int],
+                    rows: Optional[jax.Array], block_size: int
+                    ) -> Optional[jax.Array]:
     """Write token rows into pool blocks (functional update, pool donated).
 
     pool: [L, NB, bs, K, hd] — CONSUMED: the caller must drop its handle
     and use the returned array; rows: [L, n, K, hd] with
     n <= len(block_ids) * block_size, filling ``block_ids`` in sequence
     order from offset 0 (a partial final block is zero-padded; readers
-    mask it via the table's tail length).
+    mask it via the table's tail length). Any trailing row shape works:
+    the latent pool's rows are [L, n, W]. A pool that does not exist
+    (the latent family's V pool, None) stays None.
     """
+    if pool is None:
+        return None
     L, n = rows.shape[:2]
     nb = len(block_ids)
     pad = nb * block_size - n
@@ -287,13 +303,29 @@ def write_pool_rows(pool: jax.Array, block_ids: Sequence[int],
     return _write_pool_rows_jit(pool, idx, rows)
 
 
-def read_pool_rows(pool: jax.Array, block_ids: Sequence[int],
-                   block_size: int) -> jax.Array:
-    """Gather full blocks out of a pool: [L, len(block_ids)*bs, K, hd]."""
+def read_pool_rows(pool: Optional[jax.Array], block_ids: Sequence[int],
+                   block_size: int) -> Optional[jax.Array]:
+    """Gather full blocks out of a pool: [L, len(block_ids)*bs, K, hd]
+    (None from a pool that does not exist)."""
+    if pool is None:
+        return None
     idx = jnp.asarray(list(block_ids), jnp.int32)
     rows = pool[:, idx]                       # [L, nb, bs, K, hd]
     L = rows.shape[0]
     return rows.reshape((L, len(block_ids) * block_size) + rows.shape[3:])
+
+
+def rows_slice(rows: Optional[jax.Array], lo: int,
+               hi: Optional[int] = None) -> Optional[jax.Array]:
+    """Token rows [lo, hi) of an [L, n, ...] row array (None stays
+    None: the latent family moves no V rows)."""
+    return None if rows is None else rows[:, lo:hi]
+
+
+def rows_nbytes(*rows: Optional[jax.Array]) -> int:
+    """Bytes of the given row arrays (None counts 0)."""
+    return sum(int(r.size) * r.dtype.itemsize for r in rows
+               if r is not None)
 
 
 def rows_for_token_range(blocks: Sequence[int], block_size: int,
@@ -312,18 +344,22 @@ def rows_for_token_range(blocks: Sequence[int], block_size: int,
     return blk, off
 
 
-def scatter_pool_rows(pool: jax.Array, block_ids, offsets,
-                      rows: jax.Array) -> jax.Array:
+def scatter_pool_rows(pool: Optional[jax.Array], block_ids, offsets,
+                      rows: Optional[jax.Array]) -> Optional[jax.Array]:
     """Row-addressed scatter into a pool (functional update, pool donated).
 
     pool: [L, NB, bs, K, hd] — CONSUMED, continue with the returned
     array; rows: [L, n, K, hd] written at ``(block_ids[i], offsets[i])``
     per row — unlike ``write_pool_rows`` this can land mid-block, which
     is what per-chunk streaming writes into already-committed creditor
-    blocks need.
+    blocks need. A pool that does not exist (None) stays None.
     """
+    if pool is None:
+        return None
     blk = jnp.asarray(block_ids, jnp.int32)
     off = jnp.asarray(offsets, jnp.int32)
+    if pool.ndim == 4:                   # a latent pool [L, NB, bs, W]
+        return _scatter_latent_rows_jit(pool, blk, off, rows)
     return _scatter_pool_rows_jit(pool, blk, off, rows)
 
 

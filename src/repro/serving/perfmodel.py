@@ -64,9 +64,9 @@ class InstancePerfModel:
         return self.w_natn(beta) / self.f_natn(beta)
 
     def kv_bytes_per_token_layer(self) -> float:
-        """KV bytes one token adds per layer (both K and V)."""
-        c = self.cfg
-        return 2.0 * c.num_kv_heads * c.head_dim * self.bytes_per_el
+        """KV bytes one token adds per layer (both K and V, or the one
+        latent row)."""
+        return float(self.cfg.kv_elems_per_token_layer()) * self.bytes_per_el
 
     def t_atn(self, lengths: Sequence[int]) -> float:
         """Attention time of one layer: sum_r S_r / g (bandwidth bound)."""
@@ -118,8 +118,8 @@ class InstancePerfModel:
         counts on the real engine. Every extra stripe of a request adds
         one more of these exchanges per step."""
         c = self.cfg
-        q = c.num_heads * c.head_dim * self.bytes_per_el
-        o = c.num_heads * c.head_dim * 4          # f32 partial output
+        q = c.query_elems() * self.bytes_per_el
+        o = c.partial_elems() * 4                 # f32 partial output
         ml = 2 * c.num_heads * 4                  # f32 max + log-sum-exp
         return q + o + ml
 

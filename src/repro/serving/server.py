@@ -331,7 +331,9 @@ class LLMServer:
         ``trace.<name>.n`` (count) for every name in
         ``repro.serving.tracing.NAMES``. Cache, host-tier, fault and
         trace entries are present (as zeros) even when the features are
-        off/quiet, so dashboards keyed on the names never miss."""
+        off/quiet, so dashboards keyed on the names never miss. The
+        tracer's counters come as ``count.<name>``
+        (``repro.serving.tracing.COUNTERS``)."""
         cl = self.cluster
         total = used = free = 0
         spill = prefetch = hit_toks = 0
@@ -408,6 +410,7 @@ class LLMServer:
             "host_frame_corruptions": corruptions,
         })
         out.update(self.tracer.totals())
+        out.update(self.tracer.counter_totals())
         return out
 
     # --- open-loop event pump ------------------------------------------ #

@@ -18,6 +18,12 @@ Each span does two things:
   per name, always. ``LLMServer.metrics`` reports them as
   ``trace.<name>.s`` and ``trace.<name>.n``.
 
+Beside the spans, the tracer keeps running *counters* (``COUNTERS``) of
+work the serving loop reads back anyway, reported as ``count.<name>``:
+routed (token, expert) assignments of the decode steps and those that
+landed on experts this instance holds (carried back with the sampled
+tokens), and the creditor partials the decode steps computed.
+
 There is no switch: taking a profile is what turns the annotations on.
 The ``Cluster`` owns one ``Tracer``; its engines, stager, host tiers,
 preemptor and the ``LLMServer`` share it. Spans are never opened inside
@@ -46,6 +52,15 @@ NAMES = (
     "serve.readback",    # sampled tokens copied to the host
     "serve.sync",        # host blocked on in-flight KV copies
     "serve.drain",       # host-tier drains + release of finished KV
+)
+
+
+# Every counter; ``Tracer.counter_totals`` reports each as
+# ``count.<name>``.
+COUNTERS = (
+    "moe.routed",              # top-k assignments of decoded tokens
+    "moe.held",                # ... that landed on held experts
+    "attn.creditor_partials",  # (request, creditor, layer) decode partials
 )
 
 
@@ -78,6 +93,7 @@ class Tracer:
     def __init__(self):
         self.seconds: Dict[str, float] = {n: 0.0 for n in NAMES}
         self.counts: Dict[str, int] = {n: 0 for n in NAMES}
+        self.counters: Dict[str, int] = {n: 0 for n in COUNTERS}
 
     def span(self, name: str, **ids) -> Span:
         """A trace span named ``name`` (one of ``NAMES``), tagged with
@@ -85,6 +101,12 @@ class Tracer:
         if name not in self.counts:
             raise KeyError(f"unknown trace span {name!r}")
         return Span(self, name, ids)
+
+    def count(self, name: str, n: int) -> None:
+        """Add ``n`` to the counter ``name`` (one of ``COUNTERS``)."""
+        if name not in self.counters:
+            raise KeyError(f"unknown counter {name!r}")
+        self.counters[name] += int(n)
 
     def totals(self) -> Dict[str, float]:
         """``trace.<name>.s`` (seconds) and ``trace.<name>.n`` (count)
@@ -95,5 +117,9 @@ class Tracer:
             out[f"trace.{n}.n"] = float(self.counts[n])
         return out
 
+    def counter_totals(self) -> Dict[str, float]:
+        """``count.<name>`` of every counter since the server started."""
+        return {f"count.{n}": float(self.counters[n]) for n in COUNTERS}
 
-__all__ = ["NAMES", "Span", "Tracer"]
+
+__all__ = ["COUNTERS", "NAMES", "Span", "Tracer"]

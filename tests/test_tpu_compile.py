@@ -78,6 +78,22 @@ def test_paged_decode_compiles(one_chip, monkeypatch, H, K, hd, ranks, R,
     _assert_kernel(lowered)
 
 
+@pytest.mark.parametrize("R,nb,mb", [
+    pytest.param(8, 32768, 4096, id="dsv2lite-longdecode"),
+    pytest.param(8, 1024, 8, id="short-table"),
+])
+def test_paged_latent_decode_compiles(one_chip, R, nb, mb):
+    # deepseek-v2-lite: 16 heads, 576-wide latent rows stored 640 wide,
+    # 512 of them the value; the whole stacked 5-layer pool, read at a
+    # layer offset.
+    lowered = ops.paged_latent_attention.lower(
+        _spec(one_chip, (R, 16, 640)), _spec(one_chip, (5, nb, BS, 640)),
+        _spec(one_chip, (), jnp.int32), _spec(one_chip, (R, mb), jnp.int32),
+        _spec(one_chip, (R,), jnp.int32), rank=512, scale=0.1,
+        backend="pallas", interpret=False)
+    _assert_kernel(lowered)
+
+
 @pytest.mark.parametrize("C", [256, 512])
 def test_paged_prefill_compiles(one_chip, C):
     H = K = 16                # olmo-1b
